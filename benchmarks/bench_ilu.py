@@ -130,7 +130,7 @@ def solver_engine(quick=True, n_rhs=4):
     import jax.numpy as jnp
 
     from repro.core import poisson_2d
-    from repro.core.solvers import csr_to_ell_arrays, gmres, gmres_batched, make_pallas_matvec
+    from repro.core.solvers import csr_to_ell_arrays, gmres, gmres_batched, make_ell_matvec
 
     nx = 32 if quick else 128
     a = poisson_2d(nx)
@@ -141,7 +141,7 @@ def solver_engine(quick=True, n_rhs=4):
     fact = ilu(a, 1, backend="oracle")
     t1 = time.perf_counter()
     cols, vals = csr_to_ell_arrays(a)
-    matvec = make_pallas_matvec(cols, vals, a.n)
+    matvec = make_ell_matvec(cols, vals, a.n)
     precond = fact.precond()
     t2 = time.perf_counter()
 

@@ -83,7 +83,7 @@ def measure(grid: int, band_rows: int = 16, batch: int = 8) -> dict:
     assert res.converged
 
     # bitwise anchor: distributed inverse solve == single-device inverse solve
-    res1, _ = solve_with_ilu(a, b, k=1, tol=1e-6, use_pallas=False,
+    res1, _ = solve_with_ilu(a, b, k=1, tol=1e-6,
                              precond_method="inverse")
     bitwise = bool(np.array_equal(res.x.view(np.int32), res1.x.view(np.int32)))
 
@@ -124,7 +124,7 @@ def measure(grid: int, band_rows: int = 16, batch: int = 8) -> dict:
     br = rng.standard_normal(r_mat.n).astype(np.float32)
     res_r, _ = solve_sharded(r_mat, br, k=1, band_rows=band_rows, tol=1e-6,
                              precond_method="inverse")
-    res_r1, _ = solve_with_ilu(r_mat, br, k=1, tol=1e-6, use_pallas=False, precond_method="inverse")
+    res_r1, _ = solve_with_ilu(r_mat, br, k=1, tol=1e-6, precond_method="inverse")
     random_bitwise = bool(np.array_equal(res_r.x.view(np.int32), res_r1.x.view(np.int32)))
 
     return {
@@ -166,6 +166,9 @@ def measure(grid: int, band_rows: int = 16, batch: int = 8) -> dict:
 
 
 def main():
+    from repro.core.api import enable_jit_cache
+
+    enable_jit_cache()
     grid = int(sys.argv[1]) if len(sys.argv) > 1 else 32
     out = None
     if "--json" in sys.argv:

@@ -12,7 +12,7 @@ and records the service-level acceptance numbers:
 * the compile counter split at warmup (``after_warmup`` must be 0),
 * cache hit rate + refactorization count,
 * a seeded sample of responses re-solved solo
-  (``solve_with_ilu(..., use_pallas=False)``) and compared **bitwise** on
+  (``solve_with_ilu(...)``) and compared **bitwise** on
   the exact value version each request was admitted under.
 
 PR 9 adds two axes:
@@ -94,8 +94,7 @@ def serve_trajectory(n_requests: int = 2000, seed: int = 17) -> dict:
         rec = result.records[int(i)]
         resp = by_id[rec.request_id]
         ref, _ = solve_with_ilu(ref_mats[rec.expected_version], rec.b, k=K,
-                                tol=rec.tol, restart=RESTART, maxiter=MAXITER,
-                                use_pallas=False)
+                                tol=rec.tol, restart=RESTART, maxiter=MAXITER)
         bitwise_ok &= bool(np.array_equal(
             np.asarray(resp.x, np.float32).view(np.int32),
             np.asarray(ref.x, np.float32).view(np.int32)))
@@ -282,6 +281,9 @@ def _sharded_case(devices: int, n: int = 256, n_requests: int = 60) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.core.api import enable_jit_cache
+
+    enable_jit_cache()
     if "--sharded" in sys.argv:
         i = sys.argv.index("--sharded")
         print(json.dumps(sharded_trajectory(int(sys.argv[i + 1]),

@@ -96,7 +96,7 @@ def measure(grid: int, band_rows: int = 16, batch: int = 8) -> dict:
     # single-device comparison: bitwise-equal x; its first solve is NOT
     # warmed — the compile cost a cold process pays without warm_solve
     t0 = time.perf_counter()
-    res1, _ = solve_with_ilu(a, b, k=1, tol=1e-6, use_pallas=False)
+    res1, _ = solve_with_ilu(a, b, k=1, tol=1e-6)
     single_unwarmed_first_solve = time.perf_counter() - t0
     bitwise = bool(np.array_equal(res.x.view(np.int32), res1.x.view(np.int32)))
 
@@ -147,7 +147,7 @@ def measure(grid: int, band_rows: int = 16, batch: int = 8) -> dict:
         # permuted system (the PR's bitwise acceptance contract)
         ap_mat = a if name == "natural" else permuted_system(
             a, make_ordering(a, name, n_devices=d, band_rows=band_rows))
-        r_1, _ = solve_with_ilu(ap_mat, o_b, k=1, tol=1e-6, use_pallas=False)
+        r_1, _ = solve_with_ilu(ap_mat, o_b, k=1, tol=1e-6)
         x_sh = r_o.x if name == "natural" else r_o.x[
             make_ordering(a, name, n_devices=d, band_rows=band_rows).perm]
         rec["bitwise_equal_single_device_permuted"] = bool(
@@ -196,6 +196,9 @@ def measure(grid: int, band_rows: int = 16, batch: int = 8) -> dict:
 
 
 def main():
+    from repro.core.api import enable_jit_cache
+
+    enable_jit_cache()
     grid = int(sys.argv[1]) if len(sys.argv) > 1 else 32
     out = None
     if "--json" in sys.argv:

@@ -126,6 +126,9 @@ def _ordering_axis(a, band_rows: int, d: int) -> list:
 
 
 def main():
+    from repro.core.api import enable_jit_cache
+
+    enable_jit_cache()
     grid = int(sys.argv[1]) if len(sys.argv) > 1 else 32
     out = None
     if "--json" in sys.argv:

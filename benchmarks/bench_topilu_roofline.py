@@ -35,7 +35,7 @@ import time
 
 import numpy as np
 
-from repro.roofline.analysis import LINK_BW, PEAK_FLOPS, collective_bytes_per_device
+from repro.roofline.analysis import V5E, collective_bytes_per_device, peaks
 
 
 def exact_op_counts(a, pattern):
@@ -63,7 +63,7 @@ def main():
 
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 2048
     D = len(jax.devices())
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     mesh = make_mesh(np.asarray(jax.devices()).reshape(D), ("band",))
     a = matgen(n, density=min(0.02, 16.0 / n), seed=0)
@@ -83,8 +83,8 @@ def main():
             # per-superstep collective bytes (loop body counted once) x n_sup
             step_coll = sum(collective_bytes_per_device(compiled.as_text()).values())
             coll_bytes = step_coll * plan.n_supersteps
-            coll_s = coll_bytes / LINK_BW
-            comp_s = flops / D / PEAK_FLOPS
+            coll_s = coll_bytes / peaks(V5E)["link_bw"]
+            comp_s = flops / D / peaks(V5E)["flops"]
             t0 = time.perf_counter()
             got = topilu_numeric(a, pat, band_rows=band_rows, mesh=mesh, broadcast=broadcast)
             wall = (time.perf_counter() - t0) * 1e3

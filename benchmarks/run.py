@@ -20,9 +20,10 @@ iterations/sec, first/steady solve wall times) so later PRs have a perf
 trajectory to compare against. ``--emit-json BENCH_topilu.json`` runs the
 *distributed* sharded-TOP-ILU trajectory instead: 1/2/8 simulated devices,
 per-device value bytes, and the per-superstep halo collective payload from
-the roofline model (cross-checked against compiled HLO). Set ``REPRO_JIT_CACHE=<dir>`` to enable
-jax's persistent compilation cache (makes the one-time engine jit a
-once-per-machine cost instead of once-per-process).
+the roofline model (cross-checked against compiled HLO). Every process that
+runs jax turns on the persistent compilation cache
+(``repro.core.api.enable_jit_cache``); this parent imports jax only on the
+in-process CSV path.
 """
 from __future__ import annotations
 
@@ -61,13 +62,6 @@ def bench_kernels(rows, quick=True):
     np.fill_diagonal(u, np.abs(u).sum(1) + 1)
     us, _ = _t(ops.trsm_right_upper, a, jnp.asarray(u))
     rows.append(("kernel.trsm_right_upper", us, f"panel={m}x128"))
-
-    n, w = (2048, 16) if quick else (16384, 32)
-    cols = np.sort(rng.integers(0, n, (n, w)).astype(np.int32), axis=1)
-    vals = rng.standard_normal((n, w)).astype(np.float32)
-    x = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    us, _ = _t(ops.spmv_ell, jnp.asarray(cols), jnp.asarray(vals), x)
-    rows.append(("kernel.spmv_ell", us, f"nnz={n*w}"))
 
 
 def bench_paper_tables(rows, quick=True):
@@ -367,11 +361,6 @@ def main() -> None:
         emit_json = argv[i]
     if "--smoke" in argv:
         sys.exit(smoke(emit_json))
-    if os.environ.get("REPRO_JIT_CACHE"):
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-        from repro.core.api import enable_jit_cache
-
-        enable_jit_cache()
     rows = []
     topilu_metrics = None
     base = os.path.basename(emit_json) if emit_json else ""
@@ -394,6 +383,11 @@ def main() -> None:
             json.dump(payload, f, indent=2)
         print(f"wrote {emit_json}", file=sys.stderr)
         return
+    # the CSV benches run jax in this process; the trajectories above run
+    # it only in their children, which turn the cache on themselves
+    from repro.core.api import enable_jit_cache
+
+    enable_jit_cache()
     solver_metrics = bench_solver(rows, quick)
     factor_metrics = bench_factorization(rows, quick)
     bench_bitcompat(rows, quick)

@@ -9,7 +9,7 @@ sharded SpMV — L/U and A are never re-replicated onto one device — and
 asserts the whole pipeline is **bitwise equal** to the single-device path:
 the single solve, and every column of a ragged multi-RHS batch (one
 bucketed dispatch, every collective shared by the batch). Ends with the
-serving-warmup flow (``warm_solve`` + ``REPRO_JIT_CACHE``).
+serving-warmup flow (``warm_solve`` + the persistent compilation cache).
 
     python examples/distributed_solve.py [devices] [grid]   # default 4, 24
 """
@@ -18,18 +18,10 @@ import subprocess
 import sys
 
 if os.environ.get("_DIST_SOLVE_CHILD") != "1":
-    import tempfile
-
     d = sys.argv[1] if len(sys.argv) > 1 else "4"
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={d}"
     env.setdefault("JAX_PLATFORMS", "cpu")  # don't probe for real TPUs
-    # persistent compile cache: the serving setup — every engine jit and
-    # every `warm` AOT compile lands here once and is reused by later runs
-    # of this example too (stable path, not a fresh tempdir per run)
-    cache_dir = os.path.join(tempfile.gettempdir(), "repro-jit-cache")
-    os.makedirs(cache_dir, exist_ok=True)
-    env.setdefault("REPRO_JIT_CACHE", cache_dir)
     env["_DIST_SOLVE_CHILD"] = "1"
     sys.exit(subprocess.run([sys.executable, __file__] + sys.argv[1:], env=env).returncode)
 
@@ -45,7 +37,7 @@ def main():
     from repro.core.api import enable_jit_cache, ilu, ilu_sharded
     from repro.core.solvers import solve_sharded, solve_with_ilu
 
-    enable_jit_cache()  # REPRO_JIT_CACHE set by the parent: compiles persist
+    enable_jit_cache()  # every engine jit and `warm` compile persists
 
     grid = int(sys.argv[2]) if len(sys.argv) > 2 else 24
     devs = jax.devices()
@@ -85,7 +77,7 @@ def main():
     # -- distributed solve: precond + SpMV consume the sharded storage -----
     b = np.random.default_rng(0).standard_normal(a.n).astype(np.float32)
     res_d, _ = solve_sharded(a, b, k=1, band_rows=8, tol=1e-6, fact=fact)
-    res_1, _ = solve_with_ilu(a, b, k=1, tol=1e-6, use_pallas=False)
+    res_1, _ = solve_with_ilu(a, b, k=1, tol=1e-6)
     print(f"\ndistributed GMRES : {res_d.iterations:3d} iters, "
           f"residual {res_d.residual:.2e}, converged={res_d.converged}")
     print(f"single-device     : {res_1.iterations:3d} iters, " f"residual {res_1.residual:.2e}")
@@ -99,7 +91,7 @@ def main():
     print(f"\nbatched GMRES ({B.shape[0]} ragged RHS -> one bucketed "
           f"dispatch): iters {[r.iterations for r in res_b]}")
     for i, r in enumerate(res_b):
-        r1, _ = solve_with_ilu(a, B[i], k=1, tol=1e-6, use_pallas=False)
+        r1, _ = solve_with_ilu(a, B[i], k=1, tol=1e-6)
         assert r.converged
         assert np.array_equal(r.x.view(np.int32), r1.x.view(np.int32))
     print("every batch column: BITWISE EQUAL to its single-device solve ✓")
@@ -117,7 +109,7 @@ def main():
     res_w, _ = solve_sharded(a, b2, k=1, band_rows=8, tol=1e-6)
     first = time.perf_counter() - t0
     assert res_w.converged
-    print(f"\nwarmup {warm_s:.1f}s (set REPRO_JIT_CACHE to persist it); "
+    print(f"\nwarmup {warm_s:.1f}s (kept by the persistent compilation cache); "
           f"first fresh-RHS solve after warmup: {first * 1e3:.0f} ms")
 
     print(f"\nThe factors lived sharded across {d} devices for the whole "

@@ -73,8 +73,7 @@ def main():
     rec = next(r for r in result.records
                if r.matrix_id == "acme/reservoir" and r.expected_version == 1)
     resp = next(r for r in result.responses if r.request_id == rec.request_id)
-    ref, _ = solve_with_ilu(a_acme, rec.b, k=1, tol=rec.tol, restart=8,
-                            use_pallas=False)
+    ref, _ = solve_with_ilu(a_acme, rec.b, k=1, tol=rec.tol, restart=8)
     same = np.array_equal(np.asarray(resp.x, np.float32).view(np.int32),
                           np.asarray(ref.x, np.float32).view(np.int32))
     print(f"\ncoalesced (bucket {resp.batch_lanes}) vs solo: "

@@ -32,6 +32,7 @@ bitwise-equal to sequential ILU(k) of the *permuted* matrix, and
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
 from typing import Optional
 
@@ -41,32 +42,26 @@ from .sparse import CSRMatrix, ILUPattern, split_lu
 from .symbolic import symbolic_ilu_k, pilu1_symbolic
 from .numeric_ref import numeric_ilu_ref
 
-_JIT_CACHE_DIR = None
+_REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))),
+    ".jax_cache")
 
 
-def enable_jit_cache(path: str = None) -> bool:
-    """Turn on jax's persistent compilation cache (idempotent per path).
+def enable_jit_cache() -> None:
+    """Turn on jax's persistent compilation cache.
 
-    ``path`` defaults to the ``REPRO_JIT_CACHE`` environment variable; with
-    neither set this is a no-op. An explicit ``path`` always takes effect —
-    re-pointing the cache is allowed. Serving setups call it implicitly
-    through every ``warm`` entry point (``PrecondApply.warm``,
-    ``ShardedPrecondApply.warm``, ``solvers.warm_solve``), making first-use
-    engine jits a once-per-machine cost instead of once-per-process.
-    Returns True iff the cache is (now) enabled.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads the directory from
+    it and nothing here overrides it. Otherwise the cache lives at the fixed
+    path ``<repo>/.jax_cache``: the directory is part of each entry's key,
+    so a path that moved between runs would never hit. The program's entry
+    points (``chip_smoke.py``, the benchmark children, the examples) call
+    this once at start; warm entry points then persist what they compile.
     """
-    global _JIT_CACHE_DIR
-    import os
-
-    path = path or os.environ.get("REPRO_JIT_CACHE") or _JIT_CACHE_DIR
-    if not path or path == _JIT_CACHE_DIR:
-        return _JIT_CACHE_DIR is not None
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", path)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", _REPO_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-    _JIT_CACHE_DIR = path
-    return True
 
 
 @dataclasses.dataclass
@@ -93,7 +88,7 @@ class ILUFactorization:
     # describe the diagonally shifted system the ladder settled on, and
     # ``health.degraded`` routes ``precond()`` to the identity fallback.
     health: Optional["FactorHealth"] = None
-    # lazily built apply engines, keyed by (method, use_pallas) — the plan
+    # lazily built apply engines, keyed by method — the plan
     # + compiled apply are built once and reused across every
     # solve/restart/RHS batch against this factorization
     _preconds: dict = dataclasses.field(default_factory=dict, repr=False, compare=False)
@@ -101,7 +96,7 @@ class ILUFactorization:
     def lu_matrices(self):
         return split_lu(self.pattern, self.vals)
 
-    def precond(self, use_pallas: bool = True, method: Optional[str] = None):
+    def precond(self, method: Optional[str] = None):
         """The cached device-resident M^{-1} apply: ``PrecondApply`` for the
         sweep method, ``InversePrecondApply`` for the inverse chain.
         ``method`` defaults to the factorization's ``precond_method``."""
@@ -117,18 +112,16 @@ class ILUFactorization:
         method = resolve_precond_method(
             method if method is not None else self.precond_method,
             self.pattern, n_devices=1)
-        key = (method, bool(use_pallas))
-        if key not in self._preconds:
+        if method not in self._preconds:
             if method == "inverse":
                 from .inverse import InversePrecondApply
 
-                self._preconds[key] = InversePrecondApply(
-                    self.pattern, self.vals, use_pallas=key[1])
+                self._preconds[method] = InversePrecondApply(self.pattern, self.vals)
             else:
                 from .triangular import PrecondApply
 
-                self._preconds[key] = PrecondApply(self.pattern, self.vals, use_pallas=key[1])
-        return self._preconds[key]
+                self._preconds[method] = PrecondApply(self.pattern, self.vals)
+        return self._preconds[method]
 
     def solve(self, b: np.ndarray) -> np.ndarray:
         """Apply the preconditioner: solve L y = b, then U x = y.

@@ -1,4 +1,4 @@
-"""Bit-deterministic sparse row arithmetic shared by references and kernels.
+"""Bit-deterministic sparse row arithmetic shared by every solve-path engine.
 
 The paper's bit-compatibility guarantee holds only if every implementation
 of the same reduction performs the same floating-point operations in the
@@ -7,14 +7,16 @@ same order. XLA breaks that silently in two ways:
 * ``jnp.sum(..., axis=1)`` may lower to different reduction trees at
   different shapes / fusion contexts, and
 * a ``mul`` feeding an ``add`` may be contracted into an FMA in one
-  compilation and not another (observed on CPU between a monolithic jitted
-  expression and the identical code inside a Pallas block).
+  compilation and not another. XLA:CPU contracts wherever it can and
+  expands ``optimization_barrier`` before it fuses; what stops it there
+  is the lane mask ``select`` between a product and its add, which XLA
+  folds away when the mask is a constant — see :class:`hoisted_jit`.
 
 :func:`masked_lane_sum` pins the contract: products are rounded to f32
-through an ``optimization_barrier`` (no FMA contraction), then accumulated
+(through an ``optimization_barrier`` and the lane mask), then accumulated
 left-to-right in lane order. Every sparse row reduction on the solve path —
-the jnp references, the Pallas kernels, and the wavefront sweeps — goes
-through this helper so they agree bitwise by construction.
+the SpMVs, the wavefront sweeps and the inverse chain — goes through this
+helper so they agree bitwise by construction.
 """
 from __future__ import annotations
 
@@ -22,35 +24,84 @@ import jax
 import jax.numpy as jnp
 
 
-def _register_barrier_batching() -> None:
-    """Give ``optimization_barrier`` a vmap batching rule (jax<=0.4.3x ships
-    none, which breaks every barriered reduction under ``vmap`` — e.g. the
-    batched-RHS solver on the jnp fallback path). The barrier is an identity
-    on values and shapes, so batching is just applying it to the batched
-    operands with the dims passed through unchanged."""
-    try:
-        from jax.interpreters import batching
-        from jax._src.lax import lax as _lax_src
+class hoisted_jit:
+    """``jax.jit(fn)`` for a solve-path engine, compiled so that every
+    product is rounded to f32 before the add it feeds, in every context.
 
-        prim = getattr(jax.lax, "optimization_barrier_p", None) or getattr(
-            _lax_src, "optimization_barrier_p", None
-        )
-        if prim is None or prim in batching.primitive_batchers:
-            return
+    Two things undo the ``optimization_barrier`` contract on XLA:CPU, which
+    expands the barriers before it fuses:
 
-        def _rule(args, dims, **params):
-            outs = prim.bind(*args, **params)
-            if not prim.multiple_results:
-                outs, dims = (outs,), dims[0] if isinstance(dims, tuple) else dims
-                return outs[0], dims
-            return outs, dims
+    * closed-over arrays (a matvec's ELL arrays, a preconditioner's factor
+      arrays) embedded as constants let XLA fold the lane masks of
+      :func:`masked_lane_sum` away, and the exposed multiply-add is then
+      contracted into an FMA. Here every array ``fn`` closes over is passed
+      to the executable as a runtime argument instead;
+    * the CPU fusion emitters contract a multiply feeding an add within a
+      fusion, and fusion boundaries move with the batch size of a vmapped
+      engine (coalesced != solo). The engine is compiled with the classic
+      CPU emitters (``xla_cpu_use_fusion_emitters=False``, an option the
+      TPU compiler ignores).
 
-        batching.primitive_batchers[prim] = _rule
-    except Exception:  # pragma: no cover — newer jax may rename internals
-        pass
+    Called inside another trace it inlines ``fn``. ``lower(*args)`` gives
+    the AOT form: its ``compile()`` returns a callable with ``fn``'s own
+    signature, whose ``compiled`` is XLA's executable.
+    """
+
+    #: XLA options of every engine compile (see the class docstring)
+    COMPILER_OPTIONS = {"xla_cpu_use_fusion_emitters": False}
+
+    def __init__(self, fn):
+        self._fn = fn
+        self._traced = {}
+        self._run = jax.jit(self._eval, static_argnums=(0, 1),
+                            compiler_options=self.COMPILER_OPTIONS)
+
+    @staticmethod
+    def _eval(jaxpr, out_tree, consts, args):
+        outs = jax.core.eval_jaxpr(jaxpr, consts, *jax.tree.leaves(args))
+        return jax.tree.unflatten(out_tree, outs)
+
+    def _closed(self, args):
+        leaves, tree = jax.tree.flatten(args)
+        key = (tree, tuple((getattr(x, "shape", ()), getattr(x, "dtype", type(x)))
+                           for x in leaves))
+        hit = self._traced.get(key)
+        if hit is None:
+            closed, out_shape = jax.make_jaxpr(self._fn, return_shape=True)(*args)
+            hit = self._traced[key] = (closed, jax.tree.structure(out_shape))
+        return hit
+
+    def __call__(self, *args):
+        if any(isinstance(x, jax.core.Tracer) for x in jax.tree.leaves(args)):
+            # inside an enclosing trace: inline, so the enclosing program
+            # hoists what ``fn`` closes over along with its own arrays
+            return self._fn(*args)
+        closed, out_tree = self._closed(args)
+        return self._run(closed.jaxpr, out_tree, closed.consts, args)
+
+    def lower(self, *args):
+        closed, out_tree = self._closed(args)
+        lowered = self._run.lower(closed.jaxpr, out_tree, closed.consts, args)
+        return _HoistedLowered(lowered, closed.consts)
 
 
-_register_barrier_batching()
+class _HoistedLowered:
+    def __init__(self, lowered, consts):
+        self._lowered, self._consts = lowered, consts
+
+    def compile(self):
+        return _HoistedCompiled(self._lowered.compile(), self._consts)
+
+
+class _HoistedCompiled:
+    """A compiled engine called with ``fn``'s own signature; ``compiled``
+    is XLA's executable (``as_text()``, ``memory_analysis()``)."""
+
+    def __init__(self, compiled, consts):
+        self.compiled, self._consts = compiled, consts
+
+    def __call__(self, *args):
+        return self.compiled(self._consts, args)
 
 
 def pairwise_sum(x: jnp.ndarray) -> jnp.ndarray:
@@ -90,6 +141,115 @@ def barred(x: jnp.ndarray) -> jnp.ndarray:
     and would otherwise let a vmapped solve round differently from a
     single one."""
     return jax.lax.optimization_barrier(x)
+
+
+_MANT = 0x7FFFFF  # the f32 fraction bits
+_HIDDEN = 0x800000  # the implicit leading bit of a normal f32
+_MIN_NORMAL, _MAX_FINITE = 0x00800000, 0x7F7FFFFF  # as bit patterns
+
+
+def _exponent_field(bits):
+    return (bits >> 23) & 0xFF
+
+
+def _significand(bits):
+    """The integer significand in [2**23, 2**24) of a normal f32."""
+    return (bits & _MANT) | _HIDDEN
+
+
+def _remainder(A, B, C):
+    """``A·2**25 - C·B`` exactly, in int32, for ``A``, ``B`` in
+    [2**23, 2**24) and ``C`` below 2**26 with the result below 2**29 in
+    magnitude. ``C·B`` is taken in 12-bit limbs, so every product and
+    partial sum is an exact int32."""
+    c1, c0 = C >> 12, C & 0xFFF
+    b1, b0 = B >> 12, B & 0xFFF
+    h = (A << 1) - c1 * b1
+    k = (h << 12) - (c1 * b0 + c0 * b1)
+    return (k << 12) - c0 * b0
+
+
+def nearest_quotient(a: jnp.ndarray, b: jnp.ndarray, q: jnp.ndarray) -> jnp.ndarray:
+    """The round-to-nearest f32 quotient ``a / b``, from an approximation
+    ``q`` at most two ulps off.
+
+    Of ``q`` and its two neighbours on either side, the rounded quotient is
+    the ``c`` with the least exact remainder ``|a - c·b|``. The remainders
+    are computed in integer arithmetic on the significands, with ``a`` and
+    ``b`` taken to [1, 2) and ``c`` to the matching scale by their
+    exponents, so they are exact at every magnitude and no multiply-add
+    contraction can change them. The quotient of two f32 is never a
+    midpoint, so there is no tie. A zero, subnormal or non-finite operand
+    or ``q`` passes through as ``q``, and no candidate outside the normal
+    range is chosen."""
+    i32 = jnp.int32
+    ab, bb, qb = (jax.lax.bitcast_convert_type(x, i32) for x in (a, b, q))
+    ea, eb = _exponent_field(ab), _exponent_field(bb)
+    A, B = _significand(ab), _significand(bb)
+    sign, mag = qb & i32(-0x80000000), qb & i32(0x7FFFFFFF)
+    best, best_r = mag, jnp.full(mag.shape, jnp.iinfo(i32).max, i32)
+    for step in (-2, -1, 0, 1, 2):
+        cb = mag + step  # the float ``step`` ulps from |q|
+        # c's scale next to a, b in [1, 2): |c|·2**(eb-ea)·2**25 = significand << shift,
+        # the shift 0, 1 or 2 for every c within a few ulps of a / b
+        shift = _exponent_field(cb) - ea + eb - 125  # the biases: -127 + 2
+        r = jnp.abs(_remainder(A, B, _significand(cb) << jnp.clip(shift, 0, 2)))
+        usable = (shift >= 0) & (shift <= 2) & (cb >= _MIN_NORMAL) & (cb <= _MAX_FINITE)
+        r = jnp.where(usable, r, jnp.iinfo(i32).max)
+        best = jnp.where(r < best_r, cb, best)
+        best_r = jnp.minimum(r, best_r)
+    repaired = jax.lax.bitcast_convert_type(best | sign, jnp.float32)
+    normal = ((ea >= 1) & (ea <= 254) & (eb >= 1) & (eb <= 254)
+              & (mag >= _MIN_NORMAL) & (mag <= _MAX_FINITE))
+    return jnp.where(normal, repaired, q)
+
+
+def _significand_quotient(a, b):
+    """``a / b`` from the hardware divide of the two significands, taken to
+    [1, 2), with the exponents and sign put back in integer arithmetic.
+    The TPU's divide is within two ulps on such operands; on operands near
+    the top of the range it is not (hundreds of ulps off at 1e37 / 1e36).
+    Where the quotient leaves the normal range, the plain ``a / b``."""
+    i32 = jnp.int32
+    ab, bb = (jax.lax.bitcast_convert_type(x, i32) for x in (a, b))
+    one = i32(0x3F800000)  # the bits of 1.0
+    am, bm = (jax.lax.bitcast_convert_type((x & _MANT) | one, jnp.float32) for x in (ab, bb))
+    qb = jax.lax.bitcast_convert_type(am / bm, i32)  # in (0.5, 2)
+    ea, eb = _exponent_field(ab), _exponent_field(bb)
+    eq = _exponent_field(qb) + ea - eb
+    sign = (ab ^ bb) & i32(-0x80000000)
+    q = jax.lax.bitcast_convert_type((qb & _MANT) | (jnp.clip(eq, 1, 254) << 23) | sign,
+                                     jnp.float32)
+    normal = (ea >= 1) & (ea <= 254) & (eb >= 1) & (eb <= 254) & (eq >= 1) & (eq <= 254)
+    return jnp.where(normal, q, a / b)
+
+
+def exact_div(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``a / b`` rounded to nearest, as IEEE 754 and the NumPy oracles round
+    it. Every division the oracles perform (pivots, the U diagonal, the
+    inverse factors' diagonal) goes through here.
+
+    The TPU v5e's f32 divide is not rounded to nearest: it is off by up to
+    2 ulps on about a third of random quotients (``chip_smoke.py``'s
+    ``divide`` phase counts them), so there it is :func:`rounded_quotient`.
+    XLA's CPU divide is IEEE already and is used as it is. The branch is
+    chosen when the program is lowered for its platform; the compiled
+    program holds only one of them."""
+    return jax.lax.platform_dependent(a, b, cpu=jnp.divide, default=rounded_quotient)
+
+
+def rounded_quotient(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """``exact_div`` as it runs on the TPU, on any backend: the quotient of
+    the significands, repaired to round to nearest."""
+    return nearest_quotient(a, b, _significand_quotient(a, b))
+
+
+def lane_gather(x: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``x[idx]`` for an ELL ``(rows, W)`` index array, gathered through its
+    lane-major ``(W, rows)`` transpose — the same values. The TPU compiler
+    takes about 90 s for a (160000, 5) gather from a vector and about 2 s
+    for the transposed one (compiled for v5e)."""
+    return x[idx.T].T
 
 
 _UNROLL = 16  # lanes unrolled per graph node; wider rows scan over chunks

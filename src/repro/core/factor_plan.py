@@ -79,9 +79,9 @@ class FactorPlan:
     csr_row: np.ndarray  # (pattern.nnz,) int64 — CSR flatten gather rows
     csr_lane: np.ndarray  # (pattern.nnz,) int64 — CSR flatten gather lanes
 
-    # compiled executables, keyed by use_pallas — built once, reused across
-    # refactorizations of the same structure (see .engine())
-    _engines: dict = dataclasses.field(default_factory=dict, repr=False, compare=False)
+    # the compiled executable — built once, reused across refactorizations
+    # of the same structure (see .engine())
+    _engine: Optional[object] = dataclasses.field(default=None, repr=False, compare=False)
     _device_arrays: Optional[dict] = dataclasses.field(default=None, repr=False, compare=False)
 
     @property
@@ -103,21 +103,13 @@ class FactorPlan:
             }
         return self._device_arrays
 
-    def engine(self, use_pallas: bool = False):
-        """Cached compiled factorizer: ``(n+1, W) A-values -> (n, W) factors``.
-
-        Default is the XLA-compiled jnp engine: on this container the Pallas
-        path runs in *interpret* mode, whose per-op Python dispatch is
-        pathological for deep pivot-round scans; the two paths share one
-        implementation and are bitwise identical, so the choice is pure
-        speed. Flip to ``use_pallas=True`` on real TPU hardware
-        (``REPRO_PALLAS_INTERPRET=0``)."""
-        key = bool(use_pallas)
-        if key not in self._engines:
+    def engine(self):
+        """Cached compiled factorizer: ``(n+1, W) A-values -> (n, W) factors``."""
+        if self._engine is None:
             from .numeric_jax import make_wavefront_factorizer
 
-            self._engines[key] = make_wavefront_factorizer(self, use_pallas=key)
-        return self._engines[key]
+            self._engine = make_wavefront_factorizer(self)
+        return self._engine
 
     # -- host-side conveniences -------------------------------------------
     def scatter_values(self, a: CSRMatrix) -> np.ndarray:
@@ -132,14 +124,14 @@ class FactorPlan:
         """(n, W) padded factor values -> CSR-aligned flat values."""
         return np.asarray(vals_ell)[self.csr_row, self.csr_lane].astype(np.float32)
 
-    def factorize(self, a: Optional[CSRMatrix] = None, use_pallas: bool = False) -> np.ndarray:
+    def factorize(self, a: Optional[CSRMatrix] = None) -> np.ndarray:
         """Run the cached engine; returns CSR-aligned f32 factor values.
 
         ``a=None`` reuses the values captured at plan build; passing a new
         matrix with the same structure refactorizes without replanning.
         """
         vals_in = self.a_vals if a is None else self.scatter_values(a)
-        out = self.engine(use_pallas=use_pallas)(vals_in)
+        out = self.engine()(vals_in)
         return self.values_to_csr(np.asarray(out))
 
 

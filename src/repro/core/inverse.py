@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .bitmath import masked_lane_sum
+from .bitmath import exact_div, hoisted_jit, lane_gather, masked_lane_sum
 from .inverse_ref import inverse_pattern_ref
 from .planner import COL_SENTINEL, wavefront_schedule_ell
 from .sparse import ILUPattern
@@ -188,7 +188,7 @@ def inverse_values_jnp(f_cols, f_vals, addr, rhs, diag, limit):
         vb = jnp.broadcast_to(v[:, None, :], a.shape)
         y = r - masked_lane_sum(cb, vb, g, limit)
         if diag is not None:
-            y = y / d[:, None]
+            y = exact_div(y, d[:, None])
         w = jax.lax.dynamic_update_slice(w, y.reshape(-1), (start,))
         return (w, start + maxr * WI), None
 
@@ -219,17 +219,15 @@ def compute_inverse_values(plan: InversePlan):
 
 
 def inverse_chain_jnp(w_cols, w_vals, z_cols, z_vals, b):
-    """x = Z (W b): the fused two-SpMV preconditioner apply (jnp reference).
-
-    The Pallas kernel (``repro.kernels.inverse_chain``) runs this exact
-    computation on values read from refs; both reduce via
-    ``masked_lane_sum`` so they are bit-identical — to each other and to
-    ``inverse_apply_ref``.
-    """
+    """x = Z (W b): the fused two-SpMV preconditioner apply. Both SpMVs
+    reduce via ``masked_lane_sum``, so it is bit-identical to
+    ``inverse_apply_ref``."""
     n = b.shape[0]
     b = b.astype(jnp.float32)
-    y = masked_lane_sum(w_cols, w_vals, b[jnp.minimum(w_cols, n - 1)], COL_SENTINEL)
-    return masked_lane_sum(z_cols, z_vals, y[jnp.minimum(z_cols, n - 1)], COL_SENTINEL)
+    y = masked_lane_sum(w_cols, w_vals, lane_gather(b, jnp.minimum(w_cols, n - 1)),
+                        COL_SENTINEL)
+    return masked_lane_sum(z_cols, z_vals, lane_gather(y, jnp.minimum(z_cols, n - 1)),
+                           COL_SENTINEL)
 
 
 class InversePrecondApply:
@@ -240,47 +238,34 @@ class InversePrecondApply:
     (one scan per factor — the wavefront chain is paid here, not per
     apply), and exposes the same surface as ``PrecondApply``:
 
-    * ``apply(b)`` / ``__call__`` — jitted fused SpMV chain (the Pallas
-      ``inverse_chain`` kernel with ``use_pallas=True``, else the
-      bit-identical jnp reference), safe inside outer jitted code;
+    * ``apply(b)`` / ``__call__`` — jitted fused SpMV chain, safe inside
+      outer jitted code;
     * ``batched(B)`` — the chain ``vmap``-ped over a RHS stack;
     * ``warm(batch_sizes)`` — AOT compilation for the serving hot path.
     """
 
     def __init__(self, pattern: ILUPattern, vals: np.ndarray,
-                 use_pallas: bool = True, k=None, plan: Optional[InversePlan] = None):
+                 k=None, plan: Optional[InversePlan] = None):
         self.plan = plan if plan is not None else build_inverse_plan(pattern, vals, k=k)
         self.n = self.plan.n
         self.w_cols = jnp.asarray(self.plan.w_cols)
         self.z_cols = jnp.asarray(self.plan.z_cols)
         self.w_vals, self.z_vals = compute_inverse_values(self.plan)
-        # the ELL arrays ride as jit *arguments*, never closure constants:
-        # constant-embedded operands let XLA fold/fuse the chain with
-        # different rounding (observed 1-ulp drift), breaking the bitwise
-        # anchor — runtime operands keep the compiled arithmetic fixed
-        self._args = (self.w_cols, self.w_vals, self.z_cols, self.z_vals)
-        if use_pallas:
-            from repro.kernels import ops  # deferred: keep core importable alone
 
-            def _raw(wc, wv, zc, zv, b):
-                return ops.inverse_chain(wc, wv, zc, zv, b.astype(jnp.float32))
-        else:
-            def _raw(wc, wv, zc, zv, b):
-                return inverse_chain_jnp(wc, wv, zc, zv, b.astype(jnp.float32))
-        self._apply_fn = jax.jit(_raw)
-        self._batched_fn = jax.jit(jax.vmap(_raw, in_axes=(None, None, None, None, 0)))
+        def _raw(b):
+            return inverse_chain_jnp(self.w_cols, self.w_vals, self.z_cols, self.z_vals,
+                                     b.astype(jnp.float32))
+
+        # compiled with the ELL arrays as runtime operands, like every
+        # solve engine (bitmath.hoisted_jit)
+        self._apply = hoisted_jit(_raw)
+        self._batched = hoisted_jit(jax.vmap(_raw))
         self._aot = {}
-
-    def _apply(self, b):
-        return self._apply_fn(*self._args, b)
-
-    def _batched(self, bs):
-        return self._batched_fn(*self._args, bs)
 
     def __call__(self, b):
         ex = self._aot.get(1)
         if ex is not None and not isinstance(b, jax.core.Tracer):
-            return ex(*self._args, jnp.asarray(b, jnp.float32))
+            return ex(jnp.asarray(b, jnp.float32))
         return self._apply(b)
 
     apply = __call__
@@ -298,26 +283,23 @@ class InversePrecondApply:
         tgt = min(fit)
         if tgt > nb:
             bs = jnp.concatenate([bs, jnp.zeros((tgt - nb, self.n), jnp.float32)])
-        return self._aot[tgt](*self._args, bs)[:nb]
+        return self._aot[tgt](bs)[:nb]
 
     def warm(self, batch_sizes=(1,)):
         """AOT-compile the chain for the given RHS batch sizes (1 = the
         single-RHS apply). Returns {batch_size: compile_seconds}."""
         import time
 
-        from .api import enable_jit_cache
-
-        enable_jit_cache()
         out = {}
         for nb in batch_sizes:
             t0 = time.perf_counter()
             if nb not in self._aot:
                 if nb == 1:
                     sds = jax.ShapeDtypeStruct((self.n,), jnp.float32)
-                    self._aot[1] = self._apply_fn.lower(*self._args, sds).compile()
+                    self._aot[1] = self._apply.lower(sds).compile()
                 else:
                     sds = jax.ShapeDtypeStruct((nb, self.n), jnp.float32)
-                    self._aot[nb] = self._batched_fn.lower(*self._args, sds).compile()
+                    self._aot[nb] = self._batched.lower(sds).compile()
             out[nb] = time.perf_counter() - t0
         return out
 
@@ -344,10 +326,10 @@ class ShardedInversePrecondApply:
                  plan: Optional[InversePlan] = None):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from repro.compat import shard_map
+        from jax import shard_map
 
         if base is None:
-            base = InversePrecondApply(pattern, vals, use_pallas=False, k=k, plan=plan)
+            base = InversePrecondApply(pattern, vals, k=k, plan=plan)
         self.base = base
         self.plan = base.plan
         self.mesh = mesh
@@ -374,12 +356,14 @@ class ShardedInversePrecondApply:
 
         def chain(wc, wv, zc, zv, b):
             def one(b1):
-                y_loc = masked_lane_sum(wc, wv, b1[jnp.minimum(wc, n - 1)], COL_SENTINEL)
+                y_loc = masked_lane_sum(wc, wv, lane_gather(b1, jnp.minimum(wc, n - 1)),
+                                        COL_SENTINEL)
                 # untiled (D, rows_loc) gather + reshape: row blocks are
                 # contiguous in device order, so this is the (n_pad,) vector
                 # — and unlike tiled=True its vmap batching is bit-stable
                 y = jax.lax.all_gather(y_loc, ax).reshape(-1)
-                x_loc = masked_lane_sum(zc, zv, y[jnp.minimum(zc, n_pad - 1)], COL_SENTINEL)
+                x_loc = masked_lane_sum(zc, zv, lane_gather(y, jnp.minimum(zc, n_pad - 1)),
+                                        COL_SENTINEL)
                 x = jax.lax.all_gather(x_loc, ax).reshape(-1)
                 return x[:n]
             return jax.vmap(one)(b.astype(jnp.float32))
@@ -436,9 +420,6 @@ class ShardedInversePrecondApply:
         """AOT-compile the chain for the given RHS batch sizes."""
         import time
 
-        from .api import enable_jit_cache
-
-        enable_jit_cache()
         out = {}
         for nb in batch_sizes:
             t0 = time.perf_counter()

@@ -25,8 +25,8 @@ version of *itself*. This module is that single-threaded version: plain
 NumPy float32, every reduction an explicit multiply-then-add in ascending
 lane order, mirroring ``repro.core.bitmath.masked_lane_sum`` operation for
 operation (masked lanes add a literal +0.0; absent inverse entries gather
-0.0 *before* the multiply). The JAX engine (``repro.core.inverse``), the
-Pallas chain kernel, and the sharded apply must all reproduce these values
+0.0 *before* the multiply). The JAX engine (``repro.core.inverse``) and
+the sharded apply must both reproduce these values
 and applies bitwise, on any device count.
 """
 from __future__ import annotations

@@ -34,7 +34,6 @@ The same superstep body runs single-device (``axis_name=None``) or under
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -42,21 +41,15 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .bitmath import exact_div, hoisted_jit
 from .planner import NumericPlan
-
-_PALLAS_DISABLED = os.environ.get("REPRO_DISABLE_PALLAS", "0") == "1"
-
 
 # --------------------------------------------------------------------------
 # row-wavefront executor (single device)
 # --------------------------------------------------------------------------
 def factor_wavefront_sweeps_jnp(op_row, op_lane, op_piv, op_dlane, op_dst,
                                 dst_flat, a_vals_ext):
-    """Round-major pivot-op wavefront factorization (pure jnp reference).
-
-    The Pallas kernel (`repro.kernels.panel_update.factor_wavefront`) runs
-    this exact computation on values read from refs; both are bit-identical
-    because they share this implementation.
+    """Round-major pivot-op wavefront factorization.
 
     ``a_vals_ext``: (n+1, W) A-values on the pattern + zero scratch row;
     schedule arrays as in :class:`repro.core.factor_plan.FactorPlan`.
@@ -75,7 +68,7 @@ def factor_wavefront_sweeps_jnp(op_row, op_lane, op_piv, op_dlane, op_dst,
         pv = vals[pivs]  # (MO, W) — pivot rows, final since earlier rounds
         pdiag = jnp.where(valid, pv[idx, dlanes], jnp.float32(1))
         xp = x[idx, lanes]
-        l = xp / pdiag
+        l = exact_div(xp, pdiag)
         # multiply-then-subtract, product rounded to f32 before the add
         # (no FMA contraction) — the oracle's exact arithmetic
         contrib = lax.optimization_barrier(l[:, None] * pv)
@@ -88,32 +81,23 @@ def factor_wavefront_sweeps_jnp(op_row, op_lane, op_piv, op_dlane, op_dst,
     return vals[:n]
 
 
-def make_wavefront_factorizer(plan, use_pallas: bool = True):
+def make_wavefront_factorizer(plan):
     """Compiled ``(n+1, W) -> (n, W)`` factorizer over a FactorPlan.
 
-    The schedule arrays live on device (cached on the plan); the returned
-    callable is jitted once and reused for every refactorization of the
-    same structure. ``use_pallas`` routes through the fused Pallas kernel
-    (`repro.kernels.ops.factor_wavefront`); the jnp path is the
-    bit-identical reference.
+    The schedule arrays live on device (cached on the plan) and ride as
+    runtime arguments; the returned callable is jitted once and reused for
+    every refactorization of the same structure.
     """
     dev = plan.device_arrays()
-    if use_pallas and not _PALLAS_DISABLED:
-        from repro.kernels import ops  # deferred: keep core importable alone
 
-        def _raw(vals):
-            return ops.factor_wavefront(
-                dev["op_row"], dev["op_lane"], dev["op_piv"],
-                dev["op_dlane"], dev["op_dst"], dev["dst_flat"], vals,
-            )
-    else:
-        def _raw(vals):
-            return factor_wavefront_sweeps_jnp(
-                dev["op_row"], dev["op_lane"], dev["op_piv"],
-                dev["op_dlane"], dev["op_dst"], dev["dst_flat"], vals,
-            )
+    def _raw(vals):
+        return factor_wavefront_sweeps_jnp(
+            dev["op_row"], dev["op_lane"], dev["op_piv"],
+            dev["op_dlane"], dev["op_dst"], dev["dst_flat"],
+            jnp.asarray(vals, jnp.float32),
+        )
 
-    return jax.jit(lambda vals: _raw(jnp.asarray(vals, jnp.float32)))
+    return hoisted_jit(_raw)
 
 
 # --------------------------------------------------------------------------
@@ -206,7 +190,7 @@ def make_superstep_factorizer(
                         pvals = jnp.where(in_band, buf[jnp.clip(li, 0, R - 1)], state[addr])
                         piv = jnp.where(valid, pvals[piv_dlane[jl, p]], jnp.float32(1))
                         xp = x[jnp.minimum(p, W - 1)]
-                        l = xp / piv
+                        l = exact_div(xp, piv)
                         contrib = lax.optimization_barrier(l * pvals)
                         x = x.at[piv_dst[jl, p]].add(-contrib, mode="drop")
                         return x.at[jnp.minimum(p, W - 1)].set(jnp.where(valid, l, xp))

@@ -7,8 +7,8 @@ real system must include the solver to measure anything meaningful
 
 Execution model: **device-resident**. Each solver compiles to a single
 jitted computation — the Krylov iteration, the preconditioner application
-(fused Pallas wavefront sweep, see ``repro.core.triangular.PrecondApply``),
-the SpMV (``repro.kernels.ops.spmv_ell``), and for GMRES the restart logic
+(fused wavefront sweep, see ``repro.core.triangular.PrecondApply``),
+the ELL SpMV (:func:`make_ell_matvec`), and for GMRES the restart logic
 and the Givens-rotation least-squares solve all live inside one
 ``lax.while_loop``. There is exactly one dispatch per solve: no host
 round-trips per iteration or per restart, no host ``lstsq``. Residual
@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .bitmath import barred, bitdot, bitnorm, masked_lane_sum
+from .bitmath import barred, bitdot, bitnorm, hoisted_jit, lane_gather, masked_lane_sum
 from .planner import COL_SENTINEL
 
 def parse_batch_buckets(spec: str, source: str = "REPRO_BATCH_BUCKETS") -> tuple:
@@ -189,22 +189,12 @@ class SolveResult:
 
 
 def make_ell_matvec(cols: jnp.ndarray, vals: jnp.ndarray, n: int) -> Callable:
-    """Row-major ELL SpMV — the jnp reference the Pallas kernel must match
-    (both reduce through ``masked_lane_sum``, so they agree bitwise)."""
+    """Row-major ELL SpMV, reduced through ``masked_lane_sum`` in lane
+    order — bitwise equal to the sequential CSR row sum."""
     def matvec(x):
         xg = jnp.concatenate([x, jnp.zeros((1,), x.dtype)])
-        gathered = xg[jnp.minimum(cols, n)]
+        gathered = lane_gather(xg, jnp.minimum(cols, n))
         return masked_lane_sum(cols, vals, gathered, COL_SENTINEL)[:n]
-    return matvec
-
-
-def make_pallas_matvec(cols: jnp.ndarray, vals: jnp.ndarray, n: int) -> Callable:
-    """ELL SpMV through the Pallas kernel, whole vector as one block (the
-    solve path keeps x VMEM-resident; shard first for n beyond ~2^20)."""
-    from repro.kernels import ops
-
-    def matvec(x):
-        return ops.spmv_ell(cols, vals, x, bm=n)
     return matvec
 
 
@@ -243,7 +233,7 @@ def make_sharded_ell_matvec(a, mesh, axis: str = "band") -> Callable:
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     d = int(mesh.devices.size)
     n = a.n
@@ -256,7 +246,7 @@ def make_sharded_ell_matvec(a, mesh, axis: str = "band") -> Callable:
 
     def mv(c, v, x):
         xg = jnp.concatenate([x, jnp.zeros((1,), x.dtype)])
-        gathered = xg[jnp.minimum(c[0], n)]
+        gathered = lane_gather(xg, jnp.minimum(c[0], n))
         y = masked_lane_sum(c[0], v[0], gathered, COL_SENTINEL)  # (nb,)
         return jax.lax.all_gather(y, axis).reshape(-1)[:n]
 
@@ -368,7 +358,7 @@ def _cg_core(matvec, M, b, tol, maxiter):
 def cg(matvec, b, precond=None, tol=1e-5, maxiter=500):
     M = precond or _identity
     b = jnp.asarray(b, jnp.float32)
-    run = _cached_engine(matvec, M, ("cg", tol, maxiter), lambda: jax.jit(
+    run = _cached_engine(matvec, M, ("cg", tol, maxiter), lambda: hoisted_jit(
         functools.partial(_cg_core, matvec, M, tol=tol, maxiter=maxiter)))
     x, it, rnorm, bnorm, hist, verdict = run(b)
     rel = float(rnorm) / max(float(bnorm), 1e-30)
@@ -429,7 +419,7 @@ def _bicgstab_core(matvec, M, b, tol, maxiter):
 def bicgstab(matvec, b, precond=None, tol=1e-5, maxiter=500):
     M = precond or _identity
     b = jnp.asarray(b, jnp.float32)
-    run = _cached_engine(matvec, M, ("bicgstab", tol, maxiter), lambda: jax.jit(
+    run = _cached_engine(matvec, M, ("bicgstab", tol, maxiter), lambda: hoisted_jit(
         functools.partial(_bicgstab_core, matvec, M, tol=tol, maxiter=maxiter)))
     x, it, rnorm, bnorm, hist, verdict = run(b)
     rel = float(rnorm) / max(float(bnorm), 1e-30)
@@ -567,6 +557,13 @@ def _gmres_core(matvec, M, b, m, tol, maxiter):
     return x, rel, it, tot, hist, bnorm, verdict
 
 
+def gmres_engine(matvec, M, restart, tol, maxiter):
+    """The compiled single-RHS GMRES engine over ``matvec`` and the
+    preconditioner ``M``, cached on ``matvec``."""
+    return _cached_engine(matvec, M, ("gmres", restart, tol, maxiter), lambda: hoisted_jit(
+        functools.partial(_gmres_core, matvec, M, m=restart, tol=tol, maxiter=maxiter)))
+
+
 def gmres(matvec, b, precond=None, restart=30, tol=1e-5, maxiter=20):
     """maxiter counts *outer* restarts. Solves A (M^{-1} u) = b, x = M^{-1} u.
 
@@ -577,9 +574,7 @@ def gmres(matvec, b, precond=None, restart=30, tol=1e-5, maxiter=20):
     solves skip straight to the compiled engine."""
     M = precond or _identity
     b = jnp.asarray(b, jnp.float32)
-    run = _cached_engine(matvec, M, ("gmres", restart, tol, maxiter), lambda: jax.jit(
-        functools.partial(_gmres_core, matvec, M, m=restart, tol=tol, maxiter=maxiter)))
-    x, rel, it, tot, hist, bnorm, verdict = run(b)
+    x, rel, it, tot, hist, bnorm, verdict = gmres_engine(matvec, M, restart, tol, maxiter)(b)
     rel = float(rel)
     return SolveResult(np.asarray(x), int(tot), rel, rel <= tol * 1.01,
                        _trim_history(hist, int(it), float(bnorm)),
@@ -607,9 +602,9 @@ def gmres_batched(matvec, bs, precond=None, restart=30, tol=1e-5, maxiter=20) ->
         raise ValueError(f"gmres_batched expects (batch, n), got shape {bs.shape}")
     tol_arr = np.asarray(tol, np.float32)
     if tol_arr.ndim == 0:
-        run = _cached_engine(matvec, M, ("gmres_batched", restart, tol, maxiter), lambda: jax.jit(
-            jax.vmap(functools.partial(_gmres_core, matvec, M, m=restart, tol=tol,
-                                       maxiter=maxiter))))
+        key = ("gmres_batched", restart, tol, maxiter)
+        run = _cached_engine(matvec, M, key, lambda: hoisted_jit(jax.vmap(
+            functools.partial(_gmres_core, matvec, M, m=restart, tol=tol, maxiter=maxiter))))
         x, rel, it, tot, hist, bnorm, verdict = run(bs)
         tols = np.full(bs.shape[0], float(tol), np.float32)
     else:
@@ -617,8 +612,9 @@ def gmres_batched(matvec, bs, precond=None, restart=30, tol=1e-5, maxiter=20) ->
             raise ValueError(
                 f"gmres_batched: per-lane tol must have shape ({bs.shape[0]},) "
                 f"matching the batch, got {tol_arr.shape}")
-        run = _cached_engine(matvec, M, ("gmres_batched_vtol", restart, maxiter), lambda: jax.jit(
-            jax.vmap(lambda b, t: _gmres_core(matvec, M, b, m=restart, tol=t, maxiter=maxiter))))
+        key = ("gmres_batched_vtol", restart, maxiter)
+        run = _cached_engine(matvec, M, key, lambda: hoisted_jit(jax.vmap(
+            lambda b, t: _gmres_core(matvec, M, b, m=restart, tol=t, maxiter=maxiter))))
         x, rel, it, tot, hist, bnorm, verdict = run(bs, jnp.asarray(tol_arr))
         tols = tol_arr
     verdict = np.asarray(verdict)
@@ -770,15 +766,12 @@ def warm_solve(a, k=1, batch_sizes=(1,), mesh=None, band_rows=32, rule="sum",
     ``solve_with_ilu``), AOT-compiles the preconditioner sweep per bucket
     (``precond.warm``), then drives one zero-RHS solve per bucket through
     the real solver entry so the Krylov engine jits land in the same
-    per-matrix caches a live solve will hit. With ``REPRO_JIT_CACHE`` set
-    the compilations persist to disk, making warmup a once-per-machine
-    cost. Returns {batch_size: warmup_seconds}.
+    per-matrix caches a live solve will hit. With the persistent
+    compilation cache on (``api.enable_jit_cache``) the compilations stay
+    on disk, making warmup a once-per-machine cost. Returns {batch_size: warmup_seconds}.
     """
     import time
 
-    from .api import enable_jit_cache
-
-    enable_jit_cache()
     out = {}
     for nb in batch_sizes:
         t0 = time.perf_counter()
@@ -806,7 +799,7 @@ def warm_solve(a, k=1, batch_sizes=(1,), mesh=None, band_rows=32, rule="sum",
 
 
 def solve_with_ilu(a, b, k=1, method="gmres", backend="jax", tol=1e-5,
-                   band_rows=32, use_pallas=True, ordering=None,
+                   band_rows=32, ordering=None,
                    precond_method=None, on_breakdown="raise", pivot_tol=None,
                    **kw):
     """End-to-end: factorize with ILU(k), then solve. Returns (SolveResult, fact).
@@ -818,9 +811,9 @@ def solve_with_ilu(a, b, k=1, method="gmres", backend="jax", tol=1e-5,
     returned ``fact`` describes the permuted system (its ``ordering``
     field carries the permutation).
 
-    The SpMV runs through the Pallas ELL kernel and the preconditioner
-    through the factorization's cached ``PrecondApply`` (fused wavefront
-    kernel) — the whole iteration is device-resident. A 2-D ``b`` of shape
+    The SpMV runs through the ELL matvec and the preconditioner through
+    the factorization's cached ``PrecondApply`` (fused wavefront sweep) —
+    the whole iteration is device-resident. A 2-D ``b`` of shape
     (batch, n) routes through ``gmres_batched`` and returns a list of
     results sharing one factorization.
 
@@ -842,18 +835,17 @@ def solve_with_ilu(a, b, k=1, method="gmres", backend="jax", tol=1e-5,
             res, fact = solve_with_ilu(
                 ap, ord_.permute_vector(np.asarray(b, np.float32)), k=k,
                 method=method, backend=backend, tol=tol, band_rows=band_rows,
-                use_pallas=use_pallas, precond_method=precond_method,
+                precond_method=precond_method,
                 on_breakdown=on_breakdown, pivot_tol=pivot_tol, **kw)
             if fact is not None and fact.ordering is None:
                 fact.ordering = ord_
             return _unpermute_results(res, ord_), fact
 
     cache = a.__dict__.setdefault("_solve_cache", {})
-    mv_key = ("matvec", bool(use_pallas))
+    mv_key = ("matvec",)
     if mv_key not in cache:
         cols, vals = csr_to_ell_arrays(a)
-        mk = make_pallas_matvec if use_pallas else make_ell_matvec
-        cache[mv_key] = mk(cols, vals, a.n)
+        cache[mv_key] = make_ell_matvec(cols, vals, a.n)
     matvec = cache[mv_key]
     fact = None
     precond = None
@@ -865,7 +857,7 @@ def solve_with_ilu(a, b, k=1, method="gmres", backend="jax", tol=1e-5,
             cache[f_key] = ilu(a, k, backend=backend, band_rows=band_rows,
                                on_breakdown=on_breakdown, pivot_tol=pivot_tol)
         fact = cache[f_key]
-        precond = fact.precond(use_pallas=use_pallas, method=precond_method)
+        precond = fact.precond(method=precond_method)
     b = jnp.asarray(b, jnp.float32)
     if b.ndim == 2:
         if method != "gmres":

@@ -39,7 +39,7 @@ from typing import Optional
 import jax
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from .planner import NumericPlan, make_plan
 from .numeric_jax import (

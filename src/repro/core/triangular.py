@@ -16,9 +16,8 @@ construction), and one ``dynamic_update_slice``. On the 16k-row Poisson
 benchmark this is ~4x faster per apply than the row-major scatter sweep.
 
 :class:`PrecondApply` caches the plan, the device-resident arrays, and the
-jitted fused L-then-U sweep (the Pallas wavefront kernel, with a jnp
-fallback) so factorizations reuse one compiled apply across solves,
-restarts, and RHS batches.
+jitted fused L-then-U sweep so factorizations reuse one compiled apply
+across solves, restarts, and RHS batches.
 
 Also provided: a fixed-sweep Jacobi triangular solve (`jacobi_sweeps>0`) —
 the TPU-friendly approximate substitution many production preconditioners
@@ -34,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .bitmath import masked_lane_sum
+from .bitmath import exact_div, hoisted_jit, masked_lane_sum
 from .planner import (
     COL_SENTINEL,
     SweepEpochSchedule,
@@ -223,35 +222,23 @@ class PrecondApply:
       for a single right-hand side, safe to call inside outer jitted code
       (it traces inline, so a whole Krylov solve stays one dispatch);
     * ``batched(B)`` — the same sweep ``vmap``-ped over a batch of RHS.
-
-    ``use_pallas=True`` routes through the fused Pallas wavefront kernel
-    (`repro.kernels.ops.tri_solve_wavefront`); the jnp path is the
-    bit-identical reference (both reduce via ``masked_lane_sum``).
     """
 
     def __init__(self, pattern: ILUPattern, vals: np.ndarray,
-                 use_pallas: bool = True, plan: Optional[TriangularPlan] = None):
+                 plan: Optional[TriangularPlan] = None):
         self.plan = plan if plan is not None else build_triangular_plan(pattern, vals)
         self.n = self.plan.n
         self._dev = self.plan.device_arrays()
-        if use_pallas:
-            from repro.kernels import ops  # deferred: keep core importable alone
 
-            def _raw(b):
-                return ops.tri_solve_wavefront(
-                    self._dev["l_cols"], self._dev["l_vals"], self._dev["l_rhs_idx"],
-                    self._dev["u_cols"], self._dev["u_vals"], self._dev["u_diag"],
-                    self._dev["u_rhs_idx"], self._dev["out_perm"], b,
-                )
-        else:
-            def _raw(b):
-                return wavefront_sweeps_jnp(
-                    self._dev["l_cols"], self._dev["l_vals"], self._dev["l_rhs_idx"],
-                    self._dev["u_cols"], self._dev["u_vals"], self._dev["u_diag"],
-                    self._dev["u_rhs_idx"], self._dev["out_perm"], b,
-                )
-        self._apply = jax.jit(lambda b: _raw(b.astype(jnp.float32)))
-        self._batched = jax.jit(jax.vmap(self._apply))
+        def _raw(b):
+            d = self._dev
+            return wavefront_sweeps_jnp(
+                d["l_cols"], d["l_vals"], d["l_rhs_idx"], d["u_cols"], d["u_vals"],
+                d["u_diag"], d["u_rhs_idx"], d["out_perm"], b.astype(jnp.float32),
+            )
+
+        self._apply = hoisted_jit(_raw)
+        self._batched = hoisted_jit(jax.vmap(_raw))
         self._aot = {}
 
     def __call__(self, b):
@@ -280,14 +267,11 @@ class PrecondApply:
 
     def warm(self, batch_sizes=(1,)):
         """AOT-compile the apply for the given RHS batch sizes (1 = the
-        single-RHS apply) and keep the executables for the hot path; with
-        ``REPRO_JIT_CACHE`` set the compilations persist across processes.
+        single-RHS apply) and keep the executables for the hot path; the
+        persistent compilation cache, when on, keeps them across processes.
         Returns {batch_size: compile_seconds}."""
         import time
 
-        from .api import enable_jit_cache
-
-        enable_jit_cache()
         out = {}
         for nb in batch_sizes:
             t0 = time.perf_counter()
@@ -303,12 +287,8 @@ class PrecondApply:
 
 
 def wavefront_sweeps_jnp(l_cols, l_vals, l_rhs_idx, u_cols, u_vals, u_diag, u_rhs_idx, out_perm, b):
-    """Fused L-then-U level-major wavefront sweep (pure jnp reference).
-
-    The Pallas kernel (`repro.kernels.tri_solve_wavefront`) runs this exact
-    computation on values read from refs; both are bit-identical because all
-    reductions go through ``masked_lane_sum``.
-    """
+    """Fused L-then-U level-major wavefront sweep; every reduction goes
+    through ``masked_lane_sum``, the sequential substitution's lane order."""
     nl_lev, maxr_l, _ = l_cols.shape
     nu_lev, maxr_u, _ = u_cols.shape
     nl_slots = nl_lev * maxr_l
@@ -335,7 +315,7 @@ def wavefront_sweeps_jnp(l_cols, l_vals, l_rhs_idx, u_cols, u_vals, u_diag, u_rh
         c, v, r, d = inp
         gathered = x[c]
         acc = masked_lane_sum(c, v, gathered, nu_slots)
-        x = jax.lax.dynamic_update_slice(x, (r - acc) / d, (start,))
+        x = jax.lax.dynamic_update_slice(x, exact_div(r - acc, d), (start,))
         return (x, start + maxr_u), None
 
     x_u = jnp.zeros(nu_slots + 1, jnp.float32)
@@ -354,11 +334,9 @@ def epoch_sweep_jnp(x, cols, vals, rhs, diag, start, limit):
     unit diagonal); ``x``: the device-local sweep vector
     ``[local | halo | scratch]``; ``start``: first write offset (= first
     level × maxr); ``limit``: the scratch address (lanes at or past it are
-    padding and masked out of the reduction). Shared verbatim by the jnp
-    engine path and the Pallas epoch kernel
-    (`repro.kernels.tri_sweep_epoch`) so the two cannot drift; all
-    reductions go through ``masked_lane_sum`` — the same lanes in the same
-    order as the single-device sweep, hence bitwise equal.
+    padding and masked out of the reduction). All reductions go through
+    ``masked_lane_sum`` — the same lanes in the same order as the
+    single-device sweep, hence bitwise equal.
     """
     maxr = cols.shape[1]
 
@@ -369,7 +347,7 @@ def epoch_sweep_jnp(x, cols, vals, rhs, diag, start, limit):
             y = r - masked_lane_sum(c, v, x[c], limit)
         else:
             c, v, r, d = inp
-            y = (r - masked_lane_sum(c, v, x[c], limit)) / d
+            y = exact_div(r - masked_lane_sum(c, v, x[c], limit), d)
         x = jax.lax.dynamic_update_slice(x, y, (s,))
         return (x, s + maxr), None
 
@@ -653,10 +631,10 @@ class ShardedTriangularEngine:
     AXIS = "band"
 
     def __init__(self, plan: ShardedTriangularPlan, mesh,
-                 broadcast: str = "gather", use_pallas: bool = False):
+                 broadcast: str = "gather"):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.launch.sharding import band_put
 
         if broadcast == "psum":  # historical alias for the XLA fast path
@@ -665,7 +643,6 @@ class ShardedTriangularEngine:
         self.plan = plan
         self.mesh = mesh
         self.broadcast = broadcast
-        self.use_pallas = use_pallas
         ax = self.AXIS
         D, s_loc, W = plan.n_devices, plan.s_loc, plan.width
         nu_slots = plan.nu_slots
@@ -734,14 +711,6 @@ class ShardedTriangularEngine:
         l_has = [e is not None for e in ls.egress]
         u_has = [e is not None for e in us.egress]
 
-        if use_pallas:
-            from repro.kernels import ops  # deferred: keep core importable alone
-
-            def local_sweep(x, c, v, r, d, start, limit):
-                return ops.epoch_sweep(x, c, v, r, d, start=start, limit=limit)
-        else:
-            local_sweep = epoch_sweep_jnp
-
         def broadcast_payload(payload, me):
             """All-to-all copy of each device's payload — (D, E), identical
             on every device. No arithmetic touches the wire."""
@@ -773,7 +742,7 @@ class ShardedTriangularEngine:
                 k = 0
                 for e in range(ls.n_epochs):
                     lo, hi = l_bounds[e], l_bounds[e + 1]
-                    x_l = local_sweep(x_l, lc[lo:hi], lv[lo:hi], l_r[lo:hi],
+                    x_l = epoch_sweep_jnp(x_l, lc[lo:hi], lv[lo:hi], l_r[lo:hi],
                                       None, lo * maxr_l, ls.scratch)
                     if l_has[e] and D > 1:
                         allp = broadcast_payload(x_l[l_eg[k]], me)
@@ -785,7 +754,7 @@ class ShardedTriangularEngine:
                 k = 0
                 for e in range(us.n_epochs):
                     lo, hi = u_bounds[e], u_bounds[e + 1]
-                    x_u = local_sweep(x_u, uc[lo:hi], uv[lo:hi], u_r[lo:hi],
+                    x_u = epoch_sweep_jnp(x_u, uc[lo:hi], uv[lo:hi], u_r[lo:hi],
                                       dg[lo:hi], lo * maxr_u, us.scratch)
                     if u_has[e] and D > 1:
                         allp = broadcast_payload(x_u[u_eg[k]], me)
@@ -854,8 +823,8 @@ class ShardedPrecondApply:
     Accepts a single ``(n,)`` right-hand side or an ``(nb, n)`` batch
     (``batched``); the batch rides through the same epoch schedule, so
     every collective is amortized across all right-hand sides. ``warm``
-    AOT-compiles the sweep for given batch sizes (serving warmup — with
-    ``REPRO_JIT_CACHE`` set the compilations persist across processes).
+    AOT-compiles the sweep for given batch sizes (serving warmup — the
+    persistent compilation cache, when on, keeps them across processes).
     Callable inside outer jitted code (a whole distributed Krylov solve
     traces into one dispatch). Pass a cached
     :class:`ShardedTriangularEngine` to rebind new values to the existing
@@ -910,15 +879,10 @@ class ShardedPrecondApply:
 
     def warm(self, batch_sizes=(1,)):
         """AOT-compile the sweep for the given RHS batch sizes and keep the
-        executables for the serving hot path. Enables jax's persistent
-        compilation cache when ``REPRO_JIT_CACHE`` is set, so a pre-warmed
-        shape never pays the first-dispatch compile — not even in a fresh
-        process. Returns {batch_size: compile_seconds}."""
+        executables for the serving hot path. Returns
+        {batch_size: compile_seconds}."""
         import time
 
-        from .api import enable_jit_cache
-
-        enable_jit_cache()
         out = {}
         for nb in batch_sizes:
             t0 = time.perf_counter()
@@ -928,15 +892,14 @@ class ShardedPrecondApply:
         return out
 
 
-def make_triangular_solver(pattern: ILUPattern, vals: np.ndarray,
-                           use_pallas: bool = False) -> Callable:
+def make_triangular_solver(pattern: ILUPattern, vals: np.ndarray) -> Callable:
     """Returns jitted ``solve(b) -> x`` applying (LU)^{-1} by substitution.
 
     Kept as the sequential-reference entry point (exact substitution order);
     prefer :class:`PrecondApply` when the solver will be applied repeatedly —
     it is the same computation with the plan and compilation cached.
     """
-    return PrecondApply(pattern, vals, use_pallas=use_pallas)
+    return PrecondApply(pattern, vals)
 
 
 def make_jacobi_triangular_solver(

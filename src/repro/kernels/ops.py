@@ -1,31 +1,24 @@
-"""Jit'd public wrappers around the Pallas kernels.
-
-Handles padding to tile multiples, dtype policy, and the
-``REPRO_DISABLE_PALLAS`` escape hatch (falls back to the jnp references —
-useful for isolating kernel bugs and for platforms without Pallas).
-
-On this container (CPU) the kernels execute with ``interpret=True``; on TPU
-set ``REPRO_PALLAS_INTERPRET=0`` to compile them for real.
-"""
+"""Jit'd public wrappers around the Pallas kernels: padding to tile
+multiples, and the kernel mode, which the platform chooses (interpret mode
+on the CPU, the compiled Mosaic lowering on a TPU)."""
 from __future__ import annotations
 
-import os
-
+import jax
 import jax.numpy as jnp
 
-from . import inverse_chain as _ic
 from . import panel_update as _pu
-from . import spmv_ell as _sp
 from . import tri_solve as _ts
-from . import tri_solve_wavefront as _tw
-from . import tri_sweep_epoch as _te
-from . import ref as _ref
-
-_DISABLED = os.environ.get("REPRO_DISABLE_PALLAS", "0") == "1"
 
 
-def _interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") == "1"
+def interpret_mode() -> bool:
+    """True on the CPU (the Pallas interpreter), False on a TPU (compiled);
+    any other backend has no Pallas lowering here and raises."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"no Pallas kernel mode for backend {backend!r}")
 
 
 def _pad2(x, m0, m1, fill=0.0):
@@ -38,94 +31,29 @@ def _pad2(x, m0, m1, fill=0.0):
 
 def panel_update(c, a, b, bm=256, bn=256, bk=128):
     """C - A @ B with automatic padding to block multiples."""
-    if _DISABLED:
-        return _ref.panel_update_ref(c, a, b)
     m, n = c.shape
     k = a.shape[1]
     bm_, bn_, bk_ = min(bm, max(m, 8)), min(bn, max(n, 8)), min(bk, max(k, 8))
     cp = _pad2(c, bm_, bn_)
     ap = _pad2(a, bm_, bk_)
     bp = _pad2(b, bk_, bn_)
-    out = _pu.panel_update(cp, ap, bp, bm=bm_, bn=bn_, bk=bk_, interpret=_interpret())
+    out = _pu.panel_update(cp, ap, bp, bm=bm_, bn=bn_, bk=bk_, interpret=interpret_mode())
     return out[:m, :n]
 
 
 def trsm_right_upper(a, u, bm=256):
     """X = A @ U^{-1} (U upper-triangular)."""
-    if _DISABLED:
-        return _ref.trsm_right_upper_ref(a, u)
     m, bs = a.shape
     bm_ = min(bm, max(m, 8))
     ap = _pad2(a, bm_, bs)
-    out = _ts.trsm_right_upper(ap, u, bm=bm_, interpret=_interpret())
+    out = _ts.trsm_right_upper(ap, u, bm=bm_, interpret=interpret_mode())
     return out[:m]
 
 
 def trsm_left_unit_lower(l, a, bn=256):
     """X = L^{-1} @ A (L unit-lower-triangular)."""
-    if _DISABLED:
-        return _ref.trsm_left_unit_lower_ref(l, a)
     bs, n = a.shape
     bn_ = min(bn, max(n, 8))
     ap = _pad2(a, bs, bn_)
-    out = _ts.trsm_left_unit_lower(l, ap, bn=bn_, interpret=_interpret())
+    out = _ts.trsm_left_unit_lower(l, ap, bn=bn_, interpret=interpret_mode())
     return out[:, :n]
-
-
-def factor_wavefront(op_row, op_lane, op_piv, op_dlane, op_dst, dst_flat, a_vals_ext):
-    """Round-major pivot-op ILU(k) numeric factorization (bit-compatible)."""
-    args = (op_row, op_lane, op_piv, op_dlane, op_dst, dst_flat, a_vals_ext)
-    if _DISABLED:
-        from repro.core.numeric_jax import factor_wavefront_sweeps_jnp
-
-        return factor_wavefront_sweeps_jnp(*args)
-    return _pu.factor_wavefront(*args, interpret=_interpret())
-
-
-def tri_solve_wavefront(l_cols, l_vals, l_rhs_idx, u_cols, u_vals, u_diag, u_rhs_idx, out_perm, b):
-    """Fused (LU)^{-1} b over level-major plan arrays (bit-compatible)."""
-    args = (l_cols, l_vals, l_rhs_idx, u_cols, u_vals, u_diag, u_rhs_idx, out_perm, b)
-    if _DISABLED:
-        return _ref.tri_solve_wavefront_ref(*args)
-    return _tw.tri_solve_wavefront(*args, interpret=_interpret())
-
-
-def epoch_sweep(x, cols, vals, rhs, diag=None, *, start, limit):
-    """Device-local levels of one sweep epoch over ``x`` (bit-compatible).
-
-    The epoch-fused building block of the sharded preconditioner apply:
-    the collectives between epochs stay outside; this is exactly the
-    compute between two exchanges (DESIGN.md §5.5).
-    """
-    if _DISABLED:
-        from repro.core.triangular import epoch_sweep_jnp
-
-        return epoch_sweep_jnp(x, cols, vals, rhs, diag, start, limit)
-    return _te.epoch_sweep(x, cols, vals, rhs, diag, start=start, limit=limit,
-                           interpret=_interpret())
-
-
-def inverse_chain(w_cols, w_vals, z_cols, z_vals, b):
-    """x = Z (W b): the fused incomplete-inverse preconditioner apply."""
-    if _DISABLED:
-        from repro.core.inverse import inverse_chain_jnp
-
-        return inverse_chain_jnp(w_cols, w_vals, z_cols, z_vals, b)
-    return _ic.inverse_chain(w_cols, w_vals, z_cols, z_vals, b, interpret=_interpret())
-
-
-def spmv_ell(cols, vals, x, bm=512):
-    """y = A @ x for sentinel-padded ELL A."""
-    if _DISABLED:
-        return _ref.spmv_ell_ref(cols, vals, x)
-    from repro.core.planner import COL_SENTINEL
-
-    n, w = cols.shape
-    bm_ = min(bm, max(n, 8))
-    pad = (-n) % bm_
-    if pad:
-        cols = jnp.pad(cols, ((0, pad), (0, 0)), constant_values=int(COL_SENTINEL))
-        vals = jnp.pad(vals, ((0, pad), (0, 0)))
-        x = jnp.pad(x, (0, pad))  # gathered only via masked lanes
-    out = _sp.spmv_ell(cols, vals, x, bm=bm_, interpret=_interpret())
-    return out[:n]

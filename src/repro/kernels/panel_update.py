@@ -1,37 +1,16 @@
-"""Pallas TPU kernels: the numeric-phase panel updates.
+"""Pallas TPU kernel: the dense Block-ILU(k) panel update.
 
-Two kernels, two granularities of the same operation (reducing a panel of
-rows against finalized pivot rows):
+:func:`panel_update` — dense trailing-panel LU update ``C <- C - A @ B``,
+the FLOP hot-spot of the Block-ILU(k) numeric phase (the MXU adaptation of
+the paper's row-merge update, DESIGN.md §3): once fill lives on 128-aligned
+tiles, every pivot step is a batch of these panel GEMMs.
 
-* :func:`panel_update` — dense trailing-panel LU update ``C <- C - A @ B``,
-  the FLOP hot-spot of the Block-ILU(k) numeric phase (the MXU adaptation
-  of the paper's row-merge update, DESIGN.md §3): once fill lives on
-  128-aligned tiles, every pivot step is a batch of these panel GEMMs.
-
-  Tiling: classic three-loop matmul grid ``(M/bm, N/bn, K/bk)``; the output
-  block is revisited along k and accumulated in VMEM; the first k-step
-  initializes from C so the subtraction costs no extra pass over HBM.
-  VMEM working set per step: bm*bk + bk*bn + bm*bn floats
-  (128³ tiles -> 192 KiB, far under the ~16 MiB VMEM budget; the default
-  bm=bn=256, bk=128 uses 384 KiB and keeps the MXU pipeline full).
-
-* :func:`factor_wavefront` — the *sparse*, bit-compatible panel update of
-  the scalar wavefront factorizer: the whole round-major pivot-op scan of
-  a ``FactorPlan`` fused into one kernel launch (each round is one panel of
-  independent rows reduced against already-final pivot rows through the
-  plan's precomputed destination-lane maps). The kernel body deliberately
-  *shares* its implementation with the jnp engine
-  (``repro.core.numeric_jax.factor_wavefront_sweeps_jnp``) so the two
-  cannot drift — bit-identity with the sequential oracle is enforced by
-  construction and asserted in the tests. Dense GEMM cannot express this
-  update bit-compatibly (a matmul reorders the oracle's per-row ascending
-  pivot recurrence), which is exactly why BILU(k) — where the GEMM kernel
-  *is* the panel update — is recorded as a different preconditioner.
-
-Caveat (same as ``tri_solve_wavefront``): this container runs Pallas in
-interpret mode (``REPRO_PALLAS_INTERPRET=1`` default); the compiled TPU
-lowering keeps the whole value array + schedule in VMEM, which bounds n —
-large-n lowering needs per-level HBM DMA (ROADMAP).
+Tiling: classic three-loop matmul grid ``(M/bm, N/bn, K/bk)``; the output
+block is revisited along k and accumulated in VMEM; the first k-step
+initializes from C so the subtraction costs no extra pass over HBM. VMEM
+working set per step: bm*bk + bk*bn + bm*bn floats (128³ tiles -> 192 KiB,
+far under the ~16 MiB VMEM budget; the default bm=bn=256, bk=128 uses
+384 KiB and keeps the MXU pipeline full).
 """
 from __future__ import annotations
 
@@ -40,8 +19,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .config import resolve_interpret
 
 
 def _kernel(a_ref, b_ref, c_ref, o_ref):
@@ -58,7 +35,7 @@ def _kernel(a_ref, b_ref, c_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
-def panel_update(c, a, b, *, bm=256, bn=256, bk=128, interpret=True):
+def panel_update(c, a, b, *, bm=256, bn=256, bk=128, interpret):
     """C - A @ B for (M,K)x(K,N); M,N,K must be multiples of the block sizes
     (ops.py pads). f32 accumulation regardless of input dtype."""
     m, k = a.shape
@@ -76,42 +53,5 @@ def panel_update(c, a, b, *, bm=256, bn=256, bk=128, interpret=True):
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), c.dtype),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(a, b, c)
-
-
-# --------------------------------------------------------------------------
-# sparse wavefront panel update (scalar ILU(k) numeric phase)
-# --------------------------------------------------------------------------
-def _factor_kernel(op_row_ref, op_lane_ref, op_piv_ref, op_dlane_ref,
-                   op_dst_ref, dst_flat_ref, a_vals_ref, o_ref):
-    from repro.core.numeric_jax import factor_wavefront_sweeps_jnp
-
-    o_ref[...] = factor_wavefront_sweeps_jnp(
-        op_row_ref[...], op_lane_ref[...], op_piv_ref[...],
-        op_dlane_ref[...], op_dst_ref[...], dst_flat_ref[...], a_vals_ref[...],
-    ).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def factor_wavefront(op_row, op_lane, op_piv, op_dlane, op_dst, dst_flat,
-                     a_vals_ext, *, interpret=True):
-    """Round-major pivot-op ILU(k) factorization in one kernel launch.
-
-    ``op_*``: (NR, MO) pivot-op schedule; ``dst_flat``: (n_ops+1, W)
-    precomputed destination lanes; ``a_vals_ext``: (n+1, W) A on the
-    pattern + scratch row. Returns the factored (n, W) values,
-    bit-identical to the jnp engine (shared implementation) and to the
-    sequential oracle.
-    """
-    n = a_vals_ext.shape[0] - 1
-    w = a_vals_ext.shape[1]
-    args = (op_row, op_lane, op_piv, op_dlane, op_dst, dst_flat, a_vals_ext)
-    return pl.pallas_call(
-        _factor_kernel,
-        in_specs=[pl.BlockSpec(a.shape, lambda *_, s=a.shape: (0,) * len(s))
-                  for a in args],
-        out_specs=pl.BlockSpec((n, w), lambda *_: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, w), a_vals_ext.dtype),
-        interpret=resolve_interpret(interpret),
-    )(*args)

@@ -1,19 +1,15 @@
 """Pure-jnp oracles for every Pallas kernel in this package.
 
 Each kernel's sweep test asserts against these references across shapes and
-dtypes; the references are also what the rest of the system uses when
-``REPRO_DISABLE_PALLAS=1``. References that sit on the bit-compatible solve
-path (`spmv_ell_ref`, the triangular-substitution refs) share their
-reduction primitive (`masked_lane_sum`) with the kernels, so kernel and
-reference agree *bitwise*, not just to tolerance.
+dtypes. The substitution-order references run the kernels' exact
+recurrences, so kernel and reference agree *bitwise*, not just to
+tolerance.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.bitmath import masked_lane_sum
-from repro.core.planner import COL_SENTINEL
 
 
 def panel_update_ref(c, a, b):
@@ -70,22 +66,3 @@ def trsm_left_unit_lower_subst_ref(l, a):
         return x.at[r, :].set((a[r, :] - acc).astype(a.dtype))
 
     return jax.lax.fori_loop(0, bs, row, x)
-
-
-def spmv_ell_ref(cols, vals, x):
-    """Row-major ELL SpMV with sentinel-padded columns — fixed lane-order
-    accumulation (bit-deterministic, matches the Pallas kernel)."""
-    n = x.shape[0]
-    xg = jnp.concatenate([x, jnp.zeros((1,), x.dtype)])
-    gathered = xg[jnp.minimum(cols, n)]
-    return masked_lane_sum(cols, vals, gathered, COL_SENTINEL)
-
-
-def tri_solve_wavefront_ref(l_cols, l_vals, l_rhs_idx, u_cols, u_vals, u_diag,
-                            u_rhs_idx, out_perm, b):
-    """Fused wavefront triangular solve, pure jnp (bitwise kernel oracle)."""
-    from repro.core.triangular import wavefront_sweeps_jnp
-
-    return wavefront_sweeps_jnp(
-        l_cols, l_vals, l_rhs_idx, u_cols, u_vals, u_diag, u_rhs_idx, out_perm, b
-    ).astype(b.dtype)

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .config import resolve_interpret
 
 
 def _right_upper_kernel(a_ref, u_ref, o_ref):
@@ -56,7 +55,7 @@ def _left_unit_lower_kernel(l_ref, a_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))
-def trsm_right_upper(a, u, *, bm=256, interpret=True):
+def trsm_right_upper(a, u, *, bm=256, interpret):
     """Solve X U = A. a: (M, bs) panel, u: (bs, bs) upper-triangular tile."""
     m, bs = a.shape
     assert u.shape == (bs, bs)
@@ -71,12 +70,12 @@ def trsm_right_upper(a, u, *, bm=256, interpret=True):
         ],
         out_specs=pl.BlockSpec((bm, bs), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, bs), a.dtype),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(a, u)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
-def trsm_left_unit_lower(l, a, *, bn=256, interpret=True):
+def trsm_left_unit_lower(l, a, *, bn=256, interpret):
     """Solve L X = A. l: (bs, bs) unit-lower tile, a: (bs, N) panel."""
     bs, n = a.shape
     assert l.shape == (bs, bs)
@@ -91,5 +90,5 @@ def trsm_left_unit_lower(l, a, *, bn=256, interpret=True):
         ],
         out_specs=pl.BlockSpec((bs, bn), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((bs, n), a.dtype),
-        interpret=resolve_interpret(interpret),
+        interpret=interpret,
     )(l, a)

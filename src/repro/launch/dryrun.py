@@ -115,7 +115,7 @@ def _build_cell(arch, shape_name, multi_pod, opts):
     # used for the roofline and full unrolls are too slow to compile for
     # every cell; layer-homogeneous extrapolation is exact here.
     from repro.roofline.analysis import (
-        analyze_costs, extract_costs, extrapolate_costs, model_flops,
+        V5E, analyze_costs, extract_costs, extrapolate_costs, model_flops,
         recurrent_scan_correction,
     )
 
@@ -142,6 +142,7 @@ def _build_cell(arch, shape_name, multi_pod, opts):
         chips=int(mesh.devices.size),
         model_flops_global=model_flops(cfg, shape_name),
         corrections=corr,
+        device_kind=V5E,
         memory_stats={
             "argument_bytes": float(mem.argument_size_in_bytes),
             "output_bytes": float(mem.output_size_in_bytes),

@@ -12,8 +12,12 @@ import math
 
 import jax
 import numpy as np
+from jax.sharding import AxisType, Mesh
 
-from repro.compat import make_mesh
+
+def make_mesh(devices, axis_names):
+    """``jax.sharding.Mesh`` over ``devices`` with Auto axis types."""
+    return Mesh(devices, axis_names, axis_types=(AxisType.Auto,) * len(axis_names))
 
 
 def _mesh(shape, axes):

@@ -19,7 +19,9 @@ and apply the standard ring-algorithm wire models:
     all-to-all        (g-1)/g * out_bytes
     collective-permute out_bytes
 
-Hardware model (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, 50 GB/s/link ICI.
+Hardware model: the published peaks of the device, from :data:`PEAKS`,
+keyed by ``device_kind``. CPU-side callers (dry runs, modelled tables)
+name the chip they model, ``V5E``.
 """
 from __future__ import annotations
 
@@ -27,9 +29,22 @@ import dataclasses
 import re
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
-LINK_BW = 50e9  # bytes/s per ICI link
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``. TPU v5e:
+#: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at
+#: 819 GB/s, 1,600 Gbit/s ICI per chip (modelled here as 50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+V5E = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table entry of ``device_kind``; an unknown kind raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind {device_kind!r}; "
+                       f"known kinds: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -178,7 +193,8 @@ def extrapolate_costs(
 
 def analyze_costs(costs: Dict[str, float], *, arch: str, shape: str, mesh_name: str,
                   chips: int, model_flops_global: float, memory_stats: Dict[str, float],
-                  corrections: Optional[Dict[str, float]] = None) -> RooflineReport:
+                  device_kind: str, corrections: Optional[Dict[str, float]] = None,
+                  ) -> RooflineReport:
     flops_dev = costs["flops"]
     bytes_dev = costs["bytes"]
     if corrections:
@@ -186,9 +202,10 @@ def analyze_costs(costs: Dict[str, float], *, arch: str, shape: str, mesh_name: 
         bytes_dev += corrections.get("bytes", 0.0)
     coll = {k.split("/", 1)[1]: v for k, v in costs.items() if k.startswith("coll/")}
     coll_total = sum(coll.values())
-    compute_s = flops_dev / PEAK_FLOPS
-    memory_s = bytes_dev / HBM_BW
-    collective_s = coll_total / LINK_BW
+    peak = peaks(device_kind)
+    compute_s = flops_dev / peak["flops"]
+    memory_s = bytes_dev / peak["hbm_bw"]
+    collective_s = coll_total / peak["link_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     bottleneck = max(terms, key=terms.get)
     useful = model_flops_global / (flops_dev * chips) if flops_dev else 0.0

@@ -105,8 +105,8 @@ def engine_fingerprint(a: CSRMatrix, pattern: ILUPattern, knobs: tuple) -> tuple
 class ServeEngine:
     """Single-device value-rebinding multi-RHS GMRES engine.
 
-    Built once per (structure, ``precond_method``, restart/maxiter,
-    ``use_pallas``); ``bind`` attaches a value version, ``solve`` runs a
+    Built once per (structure, ``precond_method``, restart/maxiter);
+    ``bind`` attaches a value version, ``solve`` runs a
     coalesced bucket, ``warm`` AOT-compiles the bucket set.
     """
 
@@ -116,11 +116,11 @@ class ServeEngine:
 
     def __init__(self, a: CSRMatrix, pattern: ILUPattern, vals_csr: np.ndarray,
                  restart: int = DEFAULT_RESTART, maxiter: int = DEFAULT_MAXITER,
-                 precond_method: str = "sweep", use_pallas: bool = True,
+                 precond_method: str = "sweep",
                  buckets: Optional[Sequence[int]] = None):
-        import jax
         import jax.numpy as jnp
 
+        from repro.core.bitmath import hoisted_jit
         from repro.core.solvers import _csr_to_ell_host, batch_buckets
 
         if precond_method not in ("sweep", "inverse"):
@@ -130,10 +130,9 @@ class ServeEngine:
         self.restart = int(restart)
         self.maxiter = int(maxiter)
         self.precond_method = precond_method
-        self.use_pallas = bool(use_pallas)
         self.buckets = tuple(batch_buckets() if buckets is None else sorted(buckets))
         self.fingerprint = engine_fingerprint(
-            a, pattern, (precond_method, self.restart, self.maxiter, self.use_pallas))
+            a, pattern, (precond_method, self.restart, self.maxiter))
 
         # --- A-side structure: ELL cols (constant) + the value scatter maps
         a_cols, _ = _csr_to_ell_host(a)
@@ -158,66 +157,41 @@ class ServeEngine:
             self._w_cols = jnp.asarray(plan0.w_cols)
             self._z_cols = jnp.asarray(plan0.z_cols)
 
-        self._jit = jax.jit(self._make_run())
+        self._jit = hoisted_jit(self._make_run())
         self._aot = {}
         self._versions = 0
 
     # -- the compiled computation ------------------------------------------
     def _make_run(self):
         import jax
-        import jax.numpy as jnp
 
-        from repro.core.bitmath import masked_lane_sum
-        from repro.core.planner import COL_SENTINEL
-        from repro.core.solvers import _gmres_core
+        from repro.core.solvers import _gmres_core, make_ell_matvec
 
         n = self.n
         m, maxiter = self.restart, self.maxiter
         a_cols = self._a_cols
-        if self.use_pallas:
-            from repro.kernels import ops
 
         def run(vargs, bs, tols):
-            # The SpMV always rides the jnp masked_lane_sum form here — the
-            # same fixed-lane-order reduction the Pallas ELL kernel runs, so
-            # it is bitwise identical to the solo Pallas matvec — because a
-            # ``vmap`` of the interpret-mode pallas_call perturbs SpMV bits
-            # (observed: ~1-ulp lane drift), while vmap of this form and of
-            # the Pallas *triangular/inverse* kernels is bit-stable. The
-            # batched sharded solver uses this form for the same reason.
-            def matvec(x):
-                xg = jnp.concatenate([x, jnp.zeros((1,), x.dtype)])
-                gathered = xg[jnp.minimum(a_cols, n)]
-                return masked_lane_sum(a_cols, vargs[0], gathered, COL_SENTINEL)[:n]
+            matvec = make_ell_matvec(a_cols, vargs[0], n)
 
             if self.precond_method == "sweep":
+                from repro.core.triangular import wavefront_sweeps_jnp
+
                 s = self._p_static
                 _, l_vals, u_vals, u_diag = vargs
 
-                if self.use_pallas:
-                    def M(x):
-                        return ops.tri_solve_wavefront(
-                            s["l_cols"], l_vals, s["l_rhs_idx"], s["u_cols"],
-                            u_vals, u_diag, s["u_rhs_idx"], s["out_perm"], x)
-                else:
-                    from repro.core.triangular import wavefront_sweeps_jnp
-
-                    def M(x):
-                        return wavefront_sweeps_jnp(
-                            s["l_cols"], l_vals, s["l_rhs_idx"], s["u_cols"],
-                            u_vals, u_diag, s["u_rhs_idx"], s["out_perm"], x)
+                def M(x):
+                    return wavefront_sweeps_jnp(
+                        s["l_cols"], l_vals, s["l_rhs_idx"], s["u_cols"],
+                        u_vals, u_diag, s["u_rhs_idx"], s["out_perm"], x)
             else:
+                from repro.core.inverse import inverse_chain_jnp
+
                 _, w_vals, z_vals = vargs
                 wc, zc = self._w_cols, self._z_cols
 
-                # always the Pallas chain: it is the vmap-bit-stable form of
-                # the inverse apply (vmapping the raw jnp chain drifts ~1 ulp
-                # — the mirror image of the SpMV case above), and it equals
-                # the solo jnp chain bitwise
-                from repro.kernels import ops as _ops
-
                 def M(x):
-                    return _ops.inverse_chain(wc, w_vals, zc, z_vals, x)
+                    return inverse_chain_jnp(wc, w_vals, zc, z_vals, x)
 
             def lane(b, t):
                 return _gmres_core(matvec, M, b, m=m, tol=t, maxiter=maxiter)
@@ -317,14 +291,11 @@ class ServeEngine:
         ]
 
     def warm(self, binding: EngineBinding, buckets: Optional[Sequence[int]] = None) -> dict:
-        """AOT-compile the engine for each bucket (serving warmup; with
-        ``REPRO_JIT_CACHE`` set the executables persist across processes).
-        Returns {bucket: seconds}."""
+        """AOT-compile the engine for each bucket (serving warmup; with the
+        persistent compilation cache on, the executables persist across
+        processes). Returns {bucket: seconds}."""
         import jax
 
-        from repro.core.api import enable_jit_cache
-
-        enable_jit_cache()
         out = {}
         for nb in buckets if buckets is not None else self.buckets:
             t0 = time.perf_counter()

@@ -82,7 +82,6 @@ class ServeConfig:
     restart: int = DEFAULT_RESTART
     maxiter: int = DEFAULT_MAXITER
     precond_method: str = "sweep"
-    use_pallas: bool = True
     buckets: Optional[Sequence[int]] = None
     sharded: bool = False                 # ShardedServeEngine over solve_sharded
     mesh: object = None                   # sharded only
@@ -125,7 +124,7 @@ class SolveService:
         if cfg.sharded:
             return ShardedServeEngine(a, pattern, vals_csr, mesh=cfg.mesh,
                                       band_rows=cfg.band_rows, k=cfg.k, **common)
-        return ServeEngine(a, pattern, vals_csr, use_pallas=cfg.use_pallas, **common)
+        return ServeEngine(a, pattern, vals_csr, **common)
 
     # -- tenant-facing surface -----------------------------------------------
     def register_matrix(self, matrix_id: str, a: CSRMatrix,

@@ -64,7 +64,7 @@ def check_ordering(n, k, band_rows, broadcast, name):
     b = np.random.default_rng(7).standard_normal(n).astype(np.float32)
     r_sh, _ = solve_sharded(a, b, k=k, band_rows=band_rows, tol=1e-6,
                             broadcast=broadcast, fact=fact)
-    r_1p, _ = solve_with_ilu(ap, b[ord_.perm], k=k, tol=1e-6, use_pallas=False)
+    r_1p, _ = solve_with_ilu(ap, b[ord_.perm], k=k, tol=1e-6)
     assert r_sh.converged and r_sh.iterations == r_1p.iterations
     assert np.array_equal(r_sh.x.view(np.int32),
                           r_1p.x[ord_.iperm].view(np.int32)), \
@@ -75,7 +75,7 @@ def check_ordering(n, k, band_rows, broadcast, name):
     rs, _ = solve_sharded(a, B, k=k, band_rows=band_rows, tol=1e-6, broadcast=broadcast, fact=fact)
     assert len(rs) == 3
     for i, r in enumerate(rs):
-        r1, _ = solve_with_ilu(ap, B[i][ord_.perm], k=k, tol=1e-6, use_pallas=False)
+        r1, _ = solve_with_ilu(ap, B[i][ord_.perm], k=k, tol=1e-6)
         assert r.converged and r.iterations == r1.iterations, i
         assert np.array_equal(r.x.view(np.int32),
                               r1.x[ord_.iperm].view(np.int32)), \
@@ -124,7 +124,7 @@ def check_inverse(n, band_rows, broadcast):
                 p = ShardedInversePrecondApply(pat, vals, mesh)
                 got_w, got_z = np.asarray(p.base.w_vals), np.asarray(p.base.z_vals)
             else:
-                p = InversePrecondApply(pat, vals, use_pallas=False)
+                p = InversePrecondApply(pat, vals)
                 got_w, got_z = np.asarray(p.w_vals), np.asarray(p.z_vals)
             assert np.array_equal(p.plan.w_cols, wc), (name, k)
             assert np.array_equal(p.plan.z_cols, zc), (name, k)
@@ -151,7 +151,7 @@ def check_inverse(n, band_rows, broadcast):
     r_sh, fact = solve_sharded(a, b, k=1, band_rows=band_rows, tol=1e-6,
                                broadcast=broadcast, ordering=ord_,
                                precond_method="inverse")
-    r_1p, _ = solve_with_ilu(ap, bp, k=1, tol=1e-6, use_pallas=False, precond_method="inverse")
+    r_1p, _ = solve_with_ilu(ap, bp, k=1, tol=1e-6, precond_method="inverse")
     x_sh = r_sh.x if ord_ is None else r_sh.x[ord_.perm]
     assert r_sh.converged and r_sh.iterations == r_1p.iterations
     assert np.array_equal(x_sh.view(np.int32), r_1p.x.view(np.int32)), \
@@ -162,7 +162,7 @@ def check_inverse(n, band_rows, broadcast):
     assert len(rs) == 3
     for i, r in enumerate(rs):
         r1, _ = solve_with_ilu(ap, B[i] if ord_ is None else B[i][ord_.perm],
-                               k=1, tol=1e-6, use_pallas=False,
+                               k=1, tol=1e-6,
                                precond_method="inverse")
         assert r.converged and r.iterations == r1.iterations, i
         xi = r.x if ord_ is None else r.x[ord_.perm]
@@ -215,11 +215,11 @@ def main():
 
         b = np.random.default_rng(7).standard_normal(n).astype(np.float32)
         ref_fact = ilu(a, k, backend="jax")
-        y_ref = np.asarray(ref_fact.precond(use_pallas=False)(b))
+        y_ref = np.asarray(ref_fact.precond()(b))
         y_sh = np.asarray(fact.precond()(b))
         assert np.array_equal(y_ref.view(np.int32), y_sh.view(np.int32)), \
             "sharded precond apply != single-device apply"
-        r_ref, _ = solve_with_ilu(a, b, k=k, tol=1e-6, use_pallas=False)
+        r_ref, _ = solve_with_ilu(a, b, k=k, tol=1e-6)
         r_sh, _ = solve_sharded(a, b, k=k, band_rows=band_rows, tol=1e-6,
                                 broadcast=broadcast, fact=fact)
         assert r_sh.converged
@@ -234,7 +234,7 @@ def main():
                               broadcast=broadcast, fact=fact)
         assert len(rs) == 3
         for i, r in enumerate(rs):
-            r1, _ = solve_with_ilu(a, B[i], k=k, tol=1e-6, use_pallas=False)
+            r1, _ = solve_with_ilu(a, B[i], k=k, tol=1e-6)
             assert r.converged and r.iterations == r1.iterations, i
             assert np.array_equal(r.x.view(np.int32), r1.x.view(np.int32)), \
                 f"batched sharded column {i} != single-device solve"

@@ -12,7 +12,7 @@ def main():
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.compat import make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_config
     from repro.models.transformer import init_stacked_layers, stack_forward
     from repro.train.pipeline import make_pipelined_forward, pipeline_bubble_fraction

@@ -138,11 +138,9 @@ def test_ladder_solve_converges_where_plain_nans():
     the shift recorded on the report."""
     a = zero_diagonal_matrix(64, 0.1, seed=4, row=0)
     b = np.random.default_rng(1).standard_normal(64).astype(np.float32)
-    r_plain, _ = solve_with_ilu(a, b, k=1, tol=1e-5, maxiter=50,
-                                use_pallas=False, on_breakdown="ignore")
+    r_plain, _ = solve_with_ilu(a, b, k=1, tol=1e-5, maxiter=50, on_breakdown="ignore")
     assert not r_plain.converged or not np.isfinite(np.asarray(r_plain.x)).all()
-    r, fact = solve_with_ilu(a, b, k=1, tol=1e-5, maxiter=200,
-                             use_pallas=False, on_breakdown="shift")
+    r, fact = solve_with_ilu(a, b, k=1, tol=1e-5, maxiter=200, on_breakdown="shift")
     assert r.converged and np.isfinite(np.asarray(r.x)).all()
     assert r.report.shift == fact.health.shift > 0
     assert r.verdict == "converged"
@@ -159,10 +157,9 @@ def test_indefinite_stagnates_then_shift_converges():
     b = np.random.default_rng(2).standard_normal(a.n).astype(np.float32)
     plain = ilu(a, 1, backend="jax", on_breakdown="raise")  # default τ: healthy
     assert plain.health.ok and plain.health.shift == 0.0
-    r0, _ = solve_with_ilu(a, b, k=1, tol=1e-5, maxiter=300, use_pallas=False)
+    r0, _ = solve_with_ilu(a, b, k=1, tol=1e-5, maxiter=300)
     assert not r0.converged and r0.verdict == "stagnated"
-    r, fact = solve_with_ilu(a, b, k=1, tol=1e-5, maxiter=300,
-                             use_pallas=False, on_breakdown="shift",
+    r, fact = solve_with_ilu(a, b, k=1, tol=1e-5, maxiter=300, on_breakdown="shift",
                              pivot_tol=1e-2)
     assert r.converged and r.verdict == "converged"
     assert r.report.shift == fact.health.shift > 0
@@ -177,7 +174,7 @@ def test_identity_fallback_when_ladder_exhausted():
     a = zero_diagonal_matrix(64, 0.1, seed=4, row=0)
     fact = ilu(a, 1, backend="jax", on_breakdown="fallback", max_shifts=0)
     assert fact.health.degraded and not fact.health.ok
-    p = fact.precond(use_pallas=False)
+    p = fact.precond()
     assert isinstance(p, IdentityPrecondApply)
     b = np.random.default_rng(2).standard_normal(64).astype(np.float32)
     assert np.array_equal(np.asarray(p(b), np.float32).view(np.int32),
@@ -215,7 +212,7 @@ def _healthy_setup(n=64, seed=8):
 
     a = matgen(n, 0.1, seed=seed)
     fact = ilu(a, 1, backend="jax")
-    pre = fact.precond(use_pallas=False)
+    pre = fact.precond()
     cols, vals = csr_to_ell_arrays(a)
     return a, make_ell_matvec(cols, vals, a.n), pre
 

@@ -1,15 +1,14 @@
-"""Level-based incomplete inverse preconditioning: oracle, engine, kernel,
-and the ``precond_method`` auto policy.
+"""Level-based incomplete inverse preconditioning: oracle, engine, fused
+chain, and the ``precond_method`` auto policy.
 
 The bit-compat contract under test (paper abstract, DESIGN.md §Inverse):
 the inverse method is NOT bitwise-comparable to classical ILU(k) — it is a
 different approximation of M^{-1} — but every execution path (jnp engine,
-Pallas chain kernel, precond apply, batched apply, warmed AOT apply) must
+fused chain, precond apply, batched apply, warmed AOT apply) must
 be bitwise-equal to the sequential NumPy oracle in
 ``repro.core.inverse_ref``. The auto-policy tests pin ``"auto"`` against
 the modeled communication records with nothing compiled.
 """
-import importlib
 import os
 import sys
 
@@ -18,6 +17,7 @@ import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core import matgen, numeric_ilu_ref, poisson_2d, symbolic_ilu_k  # noqa: E402
@@ -100,7 +100,7 @@ def test_truncated_inverse_still_preconditions(k):
 
     a = poisson_2d(8)
     b = np.random.default_rng(4).standard_normal(a.n).astype(np.float32)
-    res, _ = solve_with_ilu(a, b, k=k, tol=1e-6, use_pallas=False, precond_method="inverse")
+    res, _ = solve_with_ilu(a, b, k=k, tol=1e-6, precond_method="inverse")
     assert res.converged
 
 
@@ -120,11 +120,11 @@ def test_plan_values_bitwise_vs_oracle(k, seed):
     _assert_bitwise(got_z, want_z, "Z values != sequential oracle")
 
 
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_precond_apply_bitwise_vs_oracle(use_pallas):
+@pytest.mark.parametrize("k", [1, 2])
+def test_precond_apply_bitwise_vs_oracle(k):
     """Single apply, batched apply, and the warmed AOT paths all reproduce
-    the oracle chain bitwise (jnp engine and Pallas kernel alike)."""
-    _a, pat, vals = _factored(48, 1, seed=5)
+    the oracle chain bitwise."""
+    _a, pat, vals = _factored(48, k, seed=5)
     w_cols, z_cols = inverse_pattern_ref(pat)
     w_vals, z_vals = inverse_values_ref(pat, vals, w_cols, z_cols)
     b = np.random.default_rng(6).standard_normal(pat.n).astype(np.float32)
@@ -132,7 +132,7 @@ def test_precond_apply_bitwise_vs_oracle(use_pallas):
     want = inverse_apply_ref(w_cols, w_vals, z_cols, z_vals, b)
     want_B = inverse_apply_ref(w_cols, w_vals, z_cols, z_vals, B)
 
-    p = InversePrecondApply(pat, vals, use_pallas=use_pallas)
+    p = InversePrecondApply(pat, vals)
     _assert_bitwise(p(b), want)
     _assert_bitwise(p.batched(B), want_B)
     p.warm((1, 4))  # AOT single + bucketed batch (3 pads to 4)
@@ -142,7 +142,7 @@ def test_precond_apply_bitwise_vs_oracle(use_pallas):
 
 def test_api_precond_inverse_bitwise_and_cached():
     """``ILUFactorization.precond(method=...)`` routes and caches per
-    (method, use_pallas); D=1 ``"auto"`` resolves to the sweep engine."""
+    method; D=1 ``"auto"`` resolves to the sweep engine."""
     from repro.core.api import ilu
 
     a = matgen(64, density=0.1, seed=8)
@@ -151,11 +151,10 @@ def test_api_precond_inverse_bitwise_and_cached():
     w_cols, z_cols = inverse_pattern_ref(fact.pattern)
     w_vals, z_vals = inverse_values_ref(fact.pattern, fact.vals, w_cols, z_cols)
     want = inverse_apply_ref(w_cols, w_vals, z_cols, z_vals, b)
-    p = fact.precond(use_pallas=False, method="inverse")
+    p = fact.precond(method="inverse")
     _assert_bitwise(p(b), want)
-    assert fact.precond(use_pallas=False, method="inverse") is p
-    assert fact.precond(use_pallas=False, method="auto") is fact.precond(
-        use_pallas=False, method="sweep")
+    assert fact.precond(method="inverse") is p
+    assert fact.precond(method="auto") is fact.precond(method="sweep")
 
 
 def test_solve_with_ilu_inverse_converges_and_reuses_fact():
@@ -163,61 +162,31 @@ def test_solve_with_ilu_inverse_converges_and_reuses_fact():
 
     a = matgen(96, density=0.1, seed=11)
     b = np.random.default_rng(1).standard_normal(a.n).astype(np.float32)
-    r_sw, f1 = solve_with_ilu(a, b, k=1, tol=1e-6, use_pallas=False)
-    r_inv, f2 = solve_with_ilu(a, b, k=1, tol=1e-6, use_pallas=False, precond_method="inverse")
+    r_sw, f1 = solve_with_ilu(a, b, k=1, tol=1e-6)
+    r_inv, f2 = solve_with_ilu(a, b, k=1, tol=1e-6, precond_method="inverse")
     assert f1 is f2  # one factorization, two apply engines
     assert r_sw.converged and r_inv.converged
     # multi-RHS through gmres_batched with the inverse preconditioner
     B = np.random.default_rng(2).standard_normal((3, a.n)).astype(np.float32)
-    rs, _ = solve_with_ilu(a, B, k=1, tol=1e-6, use_pallas=False, precond_method="inverse")
+    rs, _ = solve_with_ilu(a, B, k=1, tol=1e-6, precond_method="inverse")
     assert all(r.converged for r in rs)
 
 
 # --------------------------------------------------------------------------
-# the Pallas chain kernel
+# the fused chain
 # --------------------------------------------------------------------------
-def test_inverse_chain_kernel_bitwise():
-    """Kernel (interpret), jnp reference, and the ops wrapper agree with the
-    sequential oracle apply, bit for bit."""
-    from repro.kernels import ops
-    ic = importlib.import_module("repro.kernels.inverse_chain")
-
-    _a, pat, vals = _factored(64, 1, seed=13)
+@pytest.mark.parametrize("n,seed", [(64, 13), (40, 15)])
+def test_inverse_chain_kernel_bitwise(n, seed):
+    """The fused chain, eager and jitted, agrees with the sequential oracle
+    apply, bit for bit."""
+    _a, pat, vals = _factored(n, 1, seed=seed)
     w_cols, z_cols = inverse_pattern_ref(pat)
     w_vals, z_vals = inverse_values_ref(pat, vals, w_cols, z_cols)
-    b = np.random.default_rng(14).standard_normal(pat.n).astype(np.float32)
+    b = np.random.default_rng(seed + 1).standard_normal(pat.n).astype(np.float32)
     want = inverse_apply_ref(w_cols, w_vals, z_cols, z_vals, b)
     args = tuple(jnp.asarray(x) for x in (w_cols, w_vals, z_cols, z_vals, b))
-    _assert_bitwise(ic.inverse_chain(*args, interpret=True), want)
     _assert_bitwise(inverse_chain_jnp(*args), want)
-    _assert_bitwise(ops.inverse_chain(*args), want)
-
-
-@pytest.mark.pallas_compiled
-def test_compiled_inverse_chain_bitwise():
-    ic = importlib.import_module("repro.kernels.inverse_chain")
-
-    _a, pat, vals = _factored(64, 1, seed=13)
-    w_cols, z_cols = inverse_pattern_ref(pat)
-    w_vals, z_vals = inverse_values_ref(pat, vals, w_cols, z_cols)
-    b = np.random.default_rng(14).standard_normal(pat.n).astype(np.float32)
-    want = inverse_apply_ref(w_cols, w_vals, z_cols, z_vals, b)
-    args = tuple(jnp.asarray(x) for x in (w_cols, w_vals, z_cols, z_vals, b))
-    _assert_bitwise(ic.inverse_chain(*args, interpret=False), want)
-
-
-def test_disable_pallas_escape_hatch(monkeypatch):
-    """REPRO_DISABLE_PALLAS routes ops.inverse_chain to the jnp reference
-    (one shared implementation — trivially bitwise)."""
-    from repro.kernels import ops
-
-    _a, pat, vals = _factored(40, 1, seed=15)
-    w_cols, z_cols = inverse_pattern_ref(pat)
-    w_vals, z_vals = inverse_values_ref(pat, vals, w_cols, z_cols)
-    b = np.random.default_rng(16).standard_normal(pat.n).astype(np.float32)
-    args = tuple(jnp.asarray(x) for x in (w_cols, w_vals, z_cols, z_vals, b))
-    monkeypatch.setattr(ops, "_DISABLED", True)
-    _assert_bitwise(ops.inverse_chain(*args), inverse_chain_jnp(*args))
+    _assert_bitwise(jax.jit(inverse_chain_jnp)(*args), want)
 
 
 # --------------------------------------------------------------------------

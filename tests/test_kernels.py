@@ -1,11 +1,16 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (interpret mode)."""
+"""Kernels vs their oracles: the BILU Pallas kernels against pure-jnp
+references (interpret mode on the CPU), and the solve path's jnp SpMV,
+sweeps and factorization against sequential NumPy oracles."""
 import importlib
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
+from repro.core.bitmath import hoisted_jit
 from repro.core.planner import COL_SENTINEL
+from repro.core.solvers import make_ell_matvec
 from repro.kernels import ops
 from repro.kernels import ref
 
@@ -79,9 +84,12 @@ def test_spmv_ell_sweep(n, w):
         cols[j, :m] = c
         vals[j, :m] = RNG.standard_normal(m)
     x = RNG.standard_normal(n).astype(np.float32)
-    got = np.asarray(ops.spmv_ell(jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x), bm=64))
-    want = np.asarray(ref.spmv_ell_ref(jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x)))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    got = np.asarray(make_ell_matvec(jnp.asarray(cols), jnp.asarray(vals), n)(jnp.asarray(x)))
+    dense = np.zeros((n, n))
+    rows = np.repeat(np.arange(n), w).reshape(n, w)
+    live = cols < n
+    dense[rows[live], cols[live]] = vals[live]
+    np.testing.assert_allclose(got, dense @ x, rtol=1e-5, atol=1e-5)
 
 
 def test_spmv_matches_csr():
@@ -92,16 +100,17 @@ def test_spmv_matches_csr():
     a = matgen(96, density=0.08, seed=1)
     cols, vals = csr_to_ell_arrays(a)
     x = RNG.standard_normal(a.n).astype(np.float32)
-    got = np.asarray(ops.spmv_ell(cols, vals, jnp.asarray(x)))
+    got = np.asarray(make_ell_matvec(cols, vals, a.n)(jnp.asarray(x)))
     want = a.to_scipy() @ x
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
 # --------------------------------------------------------------------------
-# Bitwise contracts: kernels vs their jnp references. The solve-path kernels
-# share `masked_lane_sum` / the substitution recurrences with the refs, so
-# the comparison is exact (int32 view), not allclose — across odd widths,
-# fully-padded sentinel rows, and block sizes that do not divide the data.
+# Bitwise contracts: the BILU kernels vs their substitution-order references,
+# and the solve-path jnp forms vs sequential NumPy oracles with explicit f32
+# rounding per operation. The comparison is exact (int32 view), not
+# allclose — across odd widths, fully-padded sentinel rows, and block sizes
+# that do not divide the data.
 # --------------------------------------------------------------------------
 def _assert_bitwise(got, want):
     np.testing.assert_array_equal(
@@ -125,16 +134,29 @@ def _rand_ell(n, w, rng, empty_every=5):
     return cols, vals
 
 
-@pytest.mark.parametrize(
-    "n,w,bm", [(64, 3, 64), (100, 7, 32), (33, 1, 8), (129, 5, 64), (256, 13, 512)]
-)
-def test_spmv_ell_bitwise_vs_ref(n, w, bm):
+def _lane_sum_ref(cols, vals, x, limit):
+    """Sequential lane-order row sums in NumPy f32: every product rounded,
+    then added left to right; masked lanes add +0.0 (masked_lane_sum)."""
+    f32 = np.float32
+    out = np.zeros(cols.shape[0], f32)
+    for j in range(cols.shape[0]):
+        acc = f32(0.0)
+        for c, v in zip(cols[j], vals[j]):
+            acc = f32(acc + (f32(f32(v) * x[c]) if c < limit else f32(0.0)))
+        out[j] = acc
+    return out
+
+
+@pytest.mark.parametrize("n,w", [(64, 3), (100, 7), (33, 1), (129, 5), (256, 13)])
+def test_spmv_ell_bitwise_vs_ref(n, w):
     rng = np.random.default_rng(n * 31 + w)
     cols, vals = _rand_ell(n, w, rng)
     x = rng.standard_normal(n).astype(np.float32)
-    got = ops.spmv_ell(jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x), bm=bm)
-    want = ref.spmv_ell_ref(jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x))
-    _assert_bitwise(got, want)
+    mv = make_ell_matvec(jnp.asarray(cols), jnp.asarray(vals), n)
+    want = _lane_sum_ref(cols, vals, x, n)
+    _assert_bitwise(mv(jnp.asarray(x)), want)
+    # compiled with its ELL arrays as runtime operands: the same bits
+    _assert_bitwise(hoisted_jit(mv)(jnp.asarray(x)), want)
 
 
 @pytest.mark.parametrize("bs,m,bm", [(8, 24, 8), (32, 200, 64), (16, 24, 16), (128, 96, 64)])
@@ -157,17 +179,18 @@ def test_trsm_left_unit_lower_bitwise_vs_subst_ref(bs, n, bn):
 
 @pytest.mark.parametrize("seed,k", [(0, 1), (2, 2)])
 def test_factor_wavefront_kernel_bitwise_vs_oracle(seed, k):
-    """The factor-side twin of the tri-solve contract: the fused Pallas
-    wavefront factorization == the sequential oracle, bit for bit."""
+    """The fused round-major wavefront factorization == the sequential
+    oracle, bit for bit."""
     from repro.core import matgen, numeric_ilu_ref, symbolic_ilu_k
     from repro.core.factor_plan import build_factor_plan
+    from repro.core.numeric_jax import factor_wavefront_sweeps_jnp
 
     a = matgen(110, density=0.06, seed=seed)
     pat = symbolic_ilu_k(a, k)
     want = numeric_ilu_ref(a, pat)
     plan = build_factor_plan(a, pat)
     dev = plan.device_arrays()
-    got = ops.factor_wavefront(
+    got = factor_wavefront_sweeps_jnp(
         dev["op_row"], dev["op_lane"], dev["op_piv"], dev["op_dlane"],
         dev["op_dst"], dev["dst_flat"], jnp.asarray(plan.a_vals),
     )
@@ -175,12 +198,16 @@ def test_factor_wavefront_kernel_bitwise_vs_oracle(seed, k):
 
 
 # --------------------------------------------------------------------------
-# Compiled (non-interpret) lowering: only meaningful on real TPU hardware.
-# Gated by the `pallas_compiled` marker + REPRO_PALLAS_INTERPRET=0 toggle
-# (see conftest.py) so CPU CI skips them cleanly.
+# Compiled (non-interpret) lowering: the platform chooses the kernel mode,
+# so this runs only where a TPU is the default backend.
 # --------------------------------------------------------------------------
-@pytest.mark.pallas_compiled
-def test_compiled_panel_update_matches_interpret():
+@pytest.fixture
+def tpu():
+    if jax.default_backend() != "tpu":
+        pytest.skip("the compiled Pallas lowering needs a TPU backend")
+
+
+def test_compiled_panel_update_matches_interpret(tpu):
     pu = importlib.import_module("repro.kernels.panel_update")
 
     a = jnp.asarray(RNG.standard_normal((128, 128)), jnp.float32)
@@ -191,54 +218,24 @@ def test_compiled_panel_update_matches_interpret():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6, atol=1e-6)
 
 
-@pytest.mark.pallas_compiled
-def test_compiled_spmv_ell_bitwise():
-    sp = importlib.import_module("repro.kernels.spmv_ell")
-
-    cols, vals = _rand_ell(256, 8, np.random.default_rng(7))
-    x = np.random.default_rng(8).standard_normal(256).astype(np.float32)
-    got = sp.spmv_ell(jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x), bm=256, interpret=False)
-    want = ref.spmv_ell_ref(jnp.asarray(cols), jnp.asarray(vals), jnp.asarray(x))
-    _assert_bitwise(got, want)
-
-
-@pytest.mark.pallas_compiled
-def test_compiled_factor_wavefront_bitwise():
-    from repro.core import matgen, numeric_ilu_ref, symbolic_ilu_k
-    from repro.core.factor_plan import build_factor_plan
-    pu = importlib.import_module("repro.kernels.panel_update")
-
-    a = matgen(96, density=0.06, seed=11)
-    pat = symbolic_ilu_k(a, 1)
-    plan = build_factor_plan(a, pat)
-    dev = plan.device_arrays()
-    got = pu.factor_wavefront(
-        dev["op_row"], dev["op_lane"], dev["op_piv"], dev["op_dlane"],
-        dev["op_dst"], dev["dst_flat"], jnp.asarray(plan.a_vals), interpret=False,
-    )
-    _assert_bitwise(plan.values_to_csr(np.asarray(got)), numeric_ilu_ref(a, pat))
-
-
 @pytest.mark.parametrize("seed,k", [(0, 1), (3, 2)])
 def test_wavefront_kernel_bit_identical_to_triangular_solver(seed, k):
-    """Regression for the PR's central claim: the fused Pallas wavefront
-    apply == the sequential-order reference solve, bit for bit."""
+    """The cached fused wavefront apply == the same sweep run eagerly on
+    the plan arrays, bit for bit: compiling it into an executable with its
+    plan arrays changes no rounding."""
     from repro.core import matgen, numeric_ilu_ref, symbolic_ilu_k
-    from repro.core.triangular import PrecondApply, make_triangular_solver
+    from repro.core.triangular import PrecondApply, wavefront_sweeps_jnp
 
     a = matgen(120, density=0.06, seed=seed)
     pat = symbolic_ilu_k(a, k)
     vals = numeric_ilu_ref(a, pat)
     b = np.random.default_rng(seed + 1).standard_normal(a.n).astype(np.float32)
-    reference = make_triangular_solver(pat, vals)  # jnp sequential-order path
-    fused = PrecondApply(pat, vals, use_pallas=True)
-    _assert_bitwise(fused(jnp.asarray(b)), reference(jnp.asarray(b)))
-    # the raw kernel against its jnp oracle on the same plan arrays
+    fused = PrecondApply(pat, vals)
     dev = fused.plan.device_arrays()
     args = (dev["l_cols"], dev["l_vals"], dev["l_rhs_idx"], dev["u_cols"],
             dev["u_vals"], dev["u_diag"], dev["u_rhs_idx"], dev["out_perm"],
             jnp.asarray(b))
-    _assert_bitwise(ops.tri_solve_wavefront(*args), ref.tri_solve_wavefront_ref(*args))
+    _assert_bitwise(fused(jnp.asarray(b)), wavefront_sweeps_jnp(*args))
 
 
 def _epoch_args(k=1, seed=5):
@@ -252,39 +249,101 @@ def _epoch_args(k=1, seed=5):
     plan = build_sharded_triangular_plan(pat, 8, 1)
     s = plan.l_sched
     rng = np.random.default_rng(seed + 1)
-    cols = jnp.asarray(s.cols_local[0])
-    vals = jnp.asarray(rng.standard_normal(cols.shape).astype(np.float32))
-    rhs = jnp.asarray(rng.standard_normal(cols.shape[:2]).astype(np.float32))
-    diag = jnp.asarray((rng.standard_normal(cols.shape[:2]) + 3).astype(np.float32))
-    x0 = jnp.zeros(s.scratch + 1, jnp.float32)
+    cols = s.cols_local[0]
+    vals = rng.standard_normal(cols.shape).astype(np.float32)
+    rhs = rng.standard_normal(cols.shape[:2]).astype(np.float32)
+    diag = (rng.standard_normal(cols.shape[:2]) + 3).astype(np.float32)
+    x0 = np.zeros(s.scratch + 1, np.float32)
     return x0, cols, vals, rhs, diag, s.scratch
 
 
 @pytest.mark.parametrize("with_diag", [False, True])
 def test_epoch_sweep_kernel_bitwise(with_diag):
-    """The epoch-fused sweep kernel == the shared jnp implementation, bit
-    for bit, for both the L (unit-diagonal) and U (divide) variants."""
+    """The epoch-fused sweep == a sequential NumPy sweep of the same epoch,
+    bit for bit, for both the L (unit-diagonal) and U (divide) variants."""
     from repro.core.triangular import epoch_sweep_jnp
-    te = importlib.import_module("repro.kernels.tri_sweep_epoch")
 
     x0, cols, vals, rhs, diag, scratch = _epoch_args()
     d = diag if with_diag else None
-    want = epoch_sweep_jnp(x0, cols, vals, rhs, d, 0, scratch)
-    got = te.epoch_sweep(x0, cols, vals, rhs, d, start=0, limit=scratch, interpret=True)
+    got = epoch_sweep_jnp(*(jnp.asarray(t) for t in (x0, cols, vals, rhs)),
+                          None if d is None else jnp.asarray(d), 0, scratch)
+    want = x0.copy()
+    maxr = cols.shape[1]
+    for lev in range(cols.shape[0]):
+        y = rhs[lev] - _lane_sum_ref(cols[lev], vals[lev], want, scratch)
+        if d is not None:
+            y = y / d[lev]
+        want[lev * maxr:(lev + 1) * maxr] = y
     _assert_bitwise(got, want)
-    # the ops wrapper (REPRO_DISABLE_PALLAS escape hatch shares the impl)
-    _assert_bitwise(ops.epoch_sweep(x0, cols, vals, rhs, d, start=0,
-                                    limit=scratch), want)
 
 
-@pytest.mark.pallas_compiled
-@pytest.mark.parametrize("with_diag", [False, True])
-def test_compiled_epoch_sweep_bitwise(with_diag):
-    from repro.core.triangular import epoch_sweep_jnp
-    te = importlib.import_module("repro.kernels.tri_sweep_epoch")
+@pytest.mark.parametrize("offset", [-2, -1, 0, 1, 2])
+def test_nearest_quotient_repairs_an_ulp(offset):
+    """exact_div's repair step: from a quotient up to two ulps off either
+    way (what the TPU's divide returns), it recovers NumPy's correctly
+    rounded a / b, bit for bit."""
+    from repro.core.bitmath import exact_div, nearest_quotient
 
-    x0, cols, vals, rhs, diag, scratch = _epoch_args(k=2, seed=9)
-    d = diag if with_diag else None
-    want = epoch_sweep_jnp(x0, cols, vals, rhs, d, 0, scratch)
-    got = te.epoch_sweep(x0, cols, vals, rhs, d, start=0, limit=scratch, interpret=False)
+    rng = np.random.default_rng(17)
+    a = (rng.standard_normal(4096) * 3).astype(np.float32)
+    b = (rng.standard_normal(4096) + 3).astype(np.float32)
+    want = a / b
+    q = want
+    for _ in range(abs(offset)):
+        q = np.nextafter(q, np.float32(np.inf * offset))
+    got = jax.jit(nearest_quotient)(jnp.asarray(a), jnp.asarray(b), jnp.asarray(q))
+    _assert_bitwise(got, want)
+    _assert_bitwise(jax.jit(exact_div)(jnp.asarray(a), jnp.asarray(b)), want)
+
+
+@pytest.mark.parametrize("a_scale,b_scale", [
+    (1e20, 1e-17),   # quotients near the f32 maximum: a tiny pivot
+    (1e37, 1e36),    # operands whose Veltkamp split would overflow unscaled
+    (1e-30, 1e-8),   # small operands, small quotients (normal range)
+    (1e-37, 3e-1),   # quotients just above the normal range's floor
+])
+def test_nearest_quotient_repairs_at_any_magnitude(a_scale, b_scale):
+    """The repair holds for quotients and operands of any magnitude whose
+    quotient is a normal f32: from two ulps off either way it recovers
+    NumPy's correctly rounded a / b, bit for bit."""
+    from repro.core.bitmath import exact_div, nearest_quotient, rounded_quotient
+
+    rng = np.random.default_rng(23)
+    a = (rng.uniform(1, 8, 4096) * rng.choice([-1, 1], 4096) * a_scale).astype(np.float32)
+    b = (rng.uniform(1, 8, 4096) * rng.choice([-1, 1], 4096) * b_scale).astype(np.float32)
+    with np.errstate(over="ignore"):
+        want = a / b
+    live = np.isfinite(want) & (np.abs(want) >= np.finfo(np.float32).tiny)
+    a, b, want = a[live], b[live], want[live]
+    assert want.size > 1000
+    repair = jax.jit(nearest_quotient)
+    for offset in (-2, 2):
+        q = np.nextafter(np.nextafter(want, np.float32(np.inf * offset)),
+                         np.float32(np.inf * offset))
+        live_q = np.isfinite(q)
+        got = repair(jnp.asarray(a[live_q]), jnp.asarray(b[live_q]), jnp.asarray(q[live_q]))
+        _assert_bitwise(got, want[live_q])
+    _assert_bitwise(jax.jit(exact_div)(jnp.asarray(a), jnp.asarray(b)), want)
+    # the TPU form of exact_div, whatever the backend
+    _assert_bitwise(jax.jit(rounded_quotient)(jnp.asarray(a), jnp.asarray(b)), want)
+
+
+@pytest.mark.parametrize("offset", [-2, -1, 1, 2])
+def test_nearest_quotient_at_binade_edges(offset):
+    """Quotients whose neighbours cross a power of two (significands at
+    the ends of [1, 2), so a / b lies next to 0.5, 1 or 2) are repaired
+    like any other."""
+    from repro.core.bitmath import nearest_quotient
+
+    one_up, two_down = np.nextafter(np.float32(1), np.float32(2)), np.nextafter(
+        np.float32(2), np.float32(0))
+    edges = np.asarray([1, one_up, 1.5, two_down], np.float32)
+    a, b = (x.ravel() for x in np.meshgrid(edges, edges))
+    a = np.concatenate([a * s for s in (1, -1e-20, 3e25)]).astype(np.float32)
+    b = np.concatenate([b * s for s in (1, 1e10, -7e-5)]).astype(np.float32)
+    want = a / b
+    q = want
+    for _ in range(abs(offset)):
+        q = np.nextafter(q, np.float32(np.inf * offset))
+    got = jax.jit(nearest_quotient)(jnp.asarray(a), jnp.asarray(b), jnp.asarray(q))
     _assert_bitwise(got, want)

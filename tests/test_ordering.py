@@ -180,17 +180,17 @@ def test_ordered_solve_boundary(spec):
     b = rng.standard_normal(a.n).astype(np.float32)
     bs = rng.standard_normal((3, a.n)).astype(np.float32)
 
-    res, fact = solve_with_ilu(a, b, k=1, tol=1e-6, use_pallas=False, ordering=spec)
+    res, fact = solve_with_ilu(a, b, k=1, tol=1e-6, ordering=spec)
     ordering = fact.ordering
     ap = permuted_system(a, ordering)
-    ref, _ = solve_with_ilu(ap, b[ordering.perm], k=1, tol=1e-6, use_pallas=False)
+    ref, _ = solve_with_ilu(ap, b[ordering.perm], k=1, tol=1e-6)
     assert res.converged and res.iterations == ref.iterations
     assert np.array_equal(res.x.view(np.int32), ref.x[ordering.iperm].view(np.int32))
     r = b - a.to_dense() @ res.x
     assert np.linalg.norm(r) <= 1e-5 * np.linalg.norm(b) * 10
 
-    rs, _ = solve_with_ilu(a, bs, k=1, tol=1e-6, use_pallas=False, ordering=spec)
-    refs, _ = solve_with_ilu(ap, bs[:, ordering.perm], k=1, tol=1e-6, use_pallas=False)
+    rs, _ = solve_with_ilu(a, bs, k=1, tol=1e-6, ordering=spec)
+    refs, _ = solve_with_ilu(ap, bs[:, ordering.perm], k=1, tol=1e-6)
     for got, want in zip(rs, refs):
         assert np.array_equal(got.x.view(np.int32), want.x[ordering.iperm].view(np.int32))
 

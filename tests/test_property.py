@@ -103,8 +103,7 @@ def test_coalescing_never_changes_bits(a, k, method, nb, pos, data):
 
     b = rng.standard_normal(a.n).astype(np.float32)
     tol = 1e-5
-    ref, _ = solve_with_ilu(a, b, k=k, tol=tol, restart=4, maxiter=30,
-                            use_pallas=False, precond_method=method)
+    ref, _ = solve_with_ilu(a, b, k=k, tol=tol, restart=4, maxiter=30, precond_method=method)
     solo = eng.solve(bind, b[None, :], np.asarray([tol], np.float32))[0]
     np.testing.assert_array_equal(
         np.asarray(solo.x, np.float32).view(np.int32),

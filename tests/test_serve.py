@@ -325,8 +325,7 @@ def test_engine_rebind_is_bitwise_and_version_monotone():
     for bind, mat in ((b1, a), (b2, a2)):
         lanes = eng.solve(bind, B, tols)
         for i in range(2):
-            ref, _ = solve_with_ilu(mat, B[i], k=1, tol=1e-6, restart=8,
-                                    use_pallas=False)
+            ref, _ = solve_with_ilu(mat, B[i], k=1, tol=1e-6, restart=8)
             np.testing.assert_array_equal(
                 np.asarray(lanes[i].x, np.float32).view(np.int32),
                 np.asarray(ref.x, np.float32).view(np.int32))
@@ -354,8 +353,7 @@ def test_coalescing_invariance_seeded(seed, k, method):
     b = rng.standard_normal(a.n).astype(np.float32)
     tol = 1e-6
     solo = eng.solve(bind, b[None, :], np.asarray([tol], np.float32))[0]
-    ref, _ = solve_with_ilu(a, b, k=k, tol=tol, restart=6, maxiter=30,
-                            use_pallas=False, precond_method=method)
+    ref, _ = solve_with_ilu(a, b, k=k, tol=tol, restart=6, maxiter=30, precond_method=method)
     np.testing.assert_array_equal(np.asarray(solo.x, np.float32).view(np.int32),
                                   np.asarray(ref.x, np.float32).view(np.int32))
 
@@ -390,7 +388,7 @@ def test_service_round_trip_and_scatter():
     for req, b in zip(reqs, bs):
         r = by_id[req.request_id]  # scatter: response matches its request
         assert r.ok and r.tenant == req.tenant and r.batch_lanes == 4
-        ref, _ = solve_with_ilu(a, b, k=1, tol=1e-5, restart=8, use_pallas=False)
+        ref, _ = solve_with_ilu(a, b, k=1, tol=1e-5, restart=8)
         np.testing.assert_array_equal(np.asarray(r.x, np.float32).view(np.int32),
                                       np.asarray(ref.x, np.float32).view(np.int32))
     # pins released: the entry is evictable again

@@ -30,7 +30,7 @@ def _rhs(n, seed):
 
 
 def _assert_bitwise_vs_solo(resp, a, b, tol, restart=8, k=1):
-    ref, _ = solve_with_ilu(a, b, k=k, tol=tol, restart=restart, use_pallas=False)
+    ref, _ = solve_with_ilu(a, b, k=k, tol=tol, restart=restart)
     np.testing.assert_array_equal(np.asarray(resp.x, np.float32).view(np.int32),
                                   np.asarray(ref.x, np.float32).view(np.int32))
 
@@ -203,3 +203,18 @@ def test_update_does_not_block_other_tenants(monkeypatch):
     assert len(resps) == 1 and resps[0].ok
     t.join()
     assert svc.cache.entry("m0").binding.version == 2
+
+
+def test_warmup_compile_failure_raises(monkeypatch):
+    """A compile or lowering error during warmup raises out of ``warmup()``
+    — it is never recorded as a failed batch for a request to meet later."""
+    svc = _svc()
+    svc.register_matrix("m0", matgen(48, 0.12, seed=14), k=1)
+
+    def refuse(*args):
+        raise RuntimeError("injected compiler refusal")
+
+    monkeypatch.setattr(svc.cache.entry("m0").engine._jit, "lower", refuse)
+    with pytest.raises(RuntimeError, match="injected compiler refusal"):
+        svc.warmup()
+    assert svc.metrics_snapshot()["requests"]["failed"] == 0

@@ -39,7 +39,7 @@ def _rhs(n, seed):
 
 
 def _assert_bitwise_vs_solo(resp, a, b, tol=1e-5, restart=8, k=1):
-    ref, _ = solve_with_ilu(a, b, k=k, tol=tol, restart=restart, use_pallas=False)
+    ref, _ = solve_with_ilu(a, b, k=k, tol=tol, restart=restart)
     np.testing.assert_array_equal(np.asarray(resp.x, np.float32).view(np.int32),
                                   np.asarray(ref.x, np.float32).view(np.int32))
 

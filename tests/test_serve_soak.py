@@ -10,7 +10,7 @@ malformed injections — then three audits over the full trail:
    path never re-enters XLA after :meth:`SolveService.warmup`, across
    every bucket size, coalescing mix, and background refactorization.
 3. **Bitwise fidelity**: every response equals the solo
-   ``solve_with_ilu(..., use_pallas=False)`` reference for the exact
+   ``solve_with_ilu(...)`` reference for the exact
    value version the request was admitted under.
 
 The compile snapshot is taken *before* computing references — reference
@@ -120,7 +120,7 @@ def test_soak_seeded_traffic_bitwise_and_compile_flat():
             "one pinned at admission")
         ref = ref_mats[(rec.matrix_id, rec.expected_version)]
         sol, _ = solve_with_ilu(ref, rec.b, k=K, tol=rec.tol,
-                                restart=RESTART, use_pallas=False)
+                                restart=RESTART)
         np.testing.assert_array_equal(
             np.asarray(resp.x, np.float32).view(np.int32),
             np.asarray(sol.x, np.float32).view(np.int32),

@@ -121,10 +121,10 @@ def test_gmres_batched_multi_rhs():
         assert rel < 5e-4
     # lanes match the single-RHS engine (same iteration counts, same answer
     # to solver tolerance)
-    from repro.core.solvers import csr_to_ell_arrays, gmres, make_pallas_matvec
+    from repro.core.solvers import csr_to_ell_arrays, gmres, make_ell_matvec
 
     cols, vals = csr_to_ell_arrays(a)
-    matvec = make_pallas_matvec(cols, vals, a.n)
+    matvec = make_ell_matvec(cols, vals, a.n)
     single = gmres(matvec, B[0], fact.precond(), tol=1e-5)
     assert single.iterations == results[0].iterations
     np.testing.assert_allclose(results[0].x, single.x, rtol=1e-4, atol=1e-5)

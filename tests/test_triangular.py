@@ -114,7 +114,7 @@ def test_solver_bitwise_vs_sequential_numpy_substitution():
             for c, v in zip(pat.indices[s + d + 1:e], vals[s + d + 1:e]):
                 acc = f32(acc + f32(f32(v) * x[c]))
             x[j] = f32(f32(y[j] - acc) / f32(vals[s + d]))
-        for solver in (make_triangular_solver(pat, vals), PrecondApply(pat, vals, use_pallas=True)):
+        for solver in (make_triangular_solver(pat, vals), PrecondApply(pat, vals)):
             got = np.asarray(solver(b))
             np.testing.assert_array_equal(got.view(np.int32), x.view(np.int32))
 
@@ -138,7 +138,7 @@ def test_precond_apply_warm_aot_bitwise():
     from repro.core.triangular import PrecondApply
 
     a, pat, vals = _setup(n=70, k=1, seed=6)
-    apply = PrecondApply(pat, vals, use_pallas=False)
+    apply = PrecondApply(pat, vals)
     b = np.random.default_rng(7).standard_normal(a.n).astype(np.float32)
     B = np.random.default_rng(8).standard_normal((3, a.n)).astype(np.float32)
     want1 = np.asarray(apply(b))
