@@ -38,6 +38,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import obs
 from .sparse import CSRMatrix, ILUPattern, split_lu
 from .symbolic import symbolic_ilu_k, pilu1_symbolic
 from .numeric_ref import numeric_ilu_ref
@@ -147,9 +148,10 @@ class ILUFactorization:
 
 
 def _symbolic(a: CSRMatrix, k: int, rule: str):
-    if k == 1:
-        return pilu1_symbolic(a, rule=rule)  # PILU(1), paper §IV-F
-    return symbolic_ilu_k(a, k, rule=rule)
+    with obs.span("ilu:plan.symbolic"):
+        if k == 1:
+            return pilu1_symbolic(a, rule=rule)  # PILU(1), paper §IV-F
+        return symbolic_ilu_k(a, k, rule=rule)
 
 
 def _resolve_ordering(a: CSRMatrix, ordering, n_devices: int, band_rows: int):

@@ -23,6 +23,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .. import obs
+
 
 class hoisted_jit:
     """``jax.jit(fn)`` for a solve-path engine, compiled so that every
@@ -45,21 +47,25 @@ class hoisted_jit:
     Called inside another trace it inlines ``fn``. ``lower(*args)`` gives
     the AOT form: its ``compile()`` returns a callable with ``fn``'s own
     signature, whose ``compiled`` is XLA's executable.
+
+    ``name`` names the engine: its XLA module is ``jit__eval_<name>``
+    (unnamed engines stay ``jit__eval``), so a profile tells the engines
+    apart. ``eval_jaxpr`` keeps the ``jax.named_scope`` stack ``fn`` was
+    traced under, so the scopes reach the compiled ops' ``op_name``. A
+    named engine registers itself with ``obs.note_program`` (a weak
+    handle); :meth:`program_texts` gives the HLO text of what its calls
+    compiled, and only then is that text made.
     """
 
     #: XLA options of every engine compile (see the class docstring)
     COMPILER_OPTIONS = {"xla_cpu_use_fusion_emitters": False}
 
-    def __init__(self, fn):
+    def __init__(self, fn, name=None):
         self._fn = fn
+        self._name = name
         self._traced = {}
-        self._run = jax.jit(self._eval, static_argnums=(0, 1),
+        self._run = jax.jit(_named_eval(name), static_argnums=(0, 1),
                             compiler_options=self.COMPILER_OPTIONS)
-
-    @staticmethod
-    def _eval(jaxpr, out_tree, consts, args):
-        outs = jax.core.eval_jaxpr(jaxpr, consts, *jax.tree.leaves(args))
-        return jax.tree.unflatten(out_tree, outs)
 
     def _closed(self, args):
         leaves, tree = jax.tree.flatten(args)
@@ -68,7 +74,8 @@ class hoisted_jit:
         hit = self._traced.get(key)
         if hit is None:
             closed, out_shape = jax.make_jaxpr(self._fn, return_shape=True)(*args)
-            hit = self._traced[key] = (closed, jax.tree.structure(out_shape))
+            # the last slot: the argument specs of the first call, once noted
+            hit = self._traced[key] = [closed, jax.tree.structure(out_shape), None]
         return hit
 
     def __call__(self, *args):
@@ -76,21 +83,63 @@ class hoisted_jit:
             # inside an enclosing trace: inline, so the enclosing program
             # hoists what ``fn`` closes over along with its own arrays
             return self._fn(*args)
-        closed, out_tree = self._closed(args)
-        return self._run(closed.jaxpr, out_tree, closed.consts, args)
+        hit = self._closed(args)
+        closed, out_tree, specs = hit
+        out = self._run(closed.jaxpr, out_tree, closed.consts, args)
+        if self._name and specs is None:
+            hit[2] = jax.tree.map(_spec, args)
+            obs.note_program(self)
+        return out
+
+    def program_texts(self):
+        """The HLO text of each program the calls compiled, lowered again
+        from the first call's argument specs (jax's caches then give the
+        executable that ran)."""
+        return [self._run.lower(closed.jaxpr, out_tree, closed.consts, specs).compile().as_text()
+                for closed, out_tree, specs in list(self._traced.values()) if specs is not None]
 
     def lower(self, *args):
-        closed, out_tree = self._closed(args)
+        closed, out_tree, _ = self._closed(args)
         lowered = self._run.lower(closed.jaxpr, out_tree, closed.consts, args)
-        return _HoistedLowered(lowered, closed.consts)
+        return _HoistedLowered(lowered, closed.consts, bool(self._name))
+
+
+def _spec(x):
+    """What ``jax.jit`` keys a compile on of one argument: its type, and
+    its placement where it was committed to a device."""
+    aval = jax.typeof(x)
+    sharding = x.sharding if getattr(x, "committed", False) else None
+    return jax.ShapeDtypeStruct(aval.shape, aval.dtype, weak_type=aval.weak_type,
+                                sharding=sharding)
+
+
+def _eval(jaxpr, out_tree, consts, args):
+    outs = jax.core.eval_jaxpr(jaxpr, consts, *jax.tree.leaves(args))
+    return jax.tree.unflatten(out_tree, outs)
+
+
+def _named_eval(name):
+    """``_eval`` under the function name ``_eval_<name>``: ``jax.jit`` names
+    the XLA module ``jit_`` + the function's name."""
+    if not name:
+        return _eval
+
+    def named(jaxpr, out_tree, consts, args):
+        return _eval(jaxpr, out_tree, consts, args)
+
+    named.__name__ = named.__qualname__ = f"_eval_{name}"
+    return named
 
 
 class _HoistedLowered:
-    def __init__(self, lowered, consts):
-        self._lowered, self._consts = lowered, consts
+    def __init__(self, lowered, consts, note=False):
+        self._lowered, self._consts, self._note = lowered, consts, note
 
     def compile(self):
-        return _HoistedCompiled(self._lowered.compile(), self._consts)
+        out = _HoistedCompiled(self._lowered.compile(), self._consts)
+        if self._note:
+            obs.note_program(out)
+        return out
 
 
 class _HoistedCompiled:
@@ -102,6 +151,9 @@ class _HoistedCompiled:
 
     def __call__(self, *args):
         return self.compiled(self._consts, args)
+
+    def program_texts(self):
+        return [self.compiled.as_text()]
 
 
 def pairwise_sum(x: jnp.ndarray) -> jnp.ndarray:

@@ -41,6 +41,7 @@ from typing import Optional
 
 import numpy as np
 
+from .. import obs
 from .planner import (
     ell_from_pattern,
     pivot_dst_flat,
@@ -129,14 +130,28 @@ class FactorPlan:
 
         ``a=None`` reuses the values captured at plan build; passing a new
         matrix with the same structure refactorizes without replanning.
+        Each host step is an ``ilu:push.*`` span (``repro.obs``): the value
+        scatter, the factorization up to its output being ready, the fetch
+        to the host and the CSR gather.
         """
-        vals_in = self.a_vals if a is None else self.scatter_values(a)
-        out = self.engine()(vals_in)
-        return self.values_to_csr(np.asarray(out))
+        with obs.span("ilu:push.scatter"):
+            vals_in = self.a_vals if a is None else self.scatter_values(a)
+        with obs.span("ilu:push.factorize"):
+            out = self.engine()(vals_in).block_until_ready()
+        with obs.span("ilu:push.fetch"):
+            out = np.asarray(out)
+        with obs.span("ilu:push.to_csr"):
+            return self.values_to_csr(out)
 
 
 def build_factor_plan(a: CSRMatrix, pattern: ILUPattern) -> FactorPlan:
-    """Vectorized host planning: pattern -> round-major pivot-op schedule."""
+    """Vectorized host planning: pattern -> round-major pivot-op schedule
+    (the ``ilu:plan.factor`` span)."""
+    with obs.span("ilu:plan.factor"):
+        return _build_factor_plan(a, pattern)
+
+
+def _build_factor_plan(a: CSRMatrix, pattern: ILUPattern) -> FactorPlan:
     n = pattern.n
     cols, vals, diag_pos, row_len, a_lane = ell_from_pattern(pattern, a, max(n, 1))
     W = cols.shape[1]

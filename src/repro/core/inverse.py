@@ -258,8 +258,8 @@ class InversePrecondApply:
 
         # compiled with the ELL arrays as runtime operands, like every
         # solve engine (bitmath.hoisted_jit)
-        self._apply = hoisted_jit(_raw)
-        self._batched = hoisted_jit(jax.vmap(_raw))
+        self._apply = hoisted_jit(_raw, name="inverse_apply")
+        self._batched = hoisted_jit(jax.vmap(_raw), name="inverse_apply_batched")
         self._aot = {}
 
     def __call__(self, b):

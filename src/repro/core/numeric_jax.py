@@ -77,7 +77,8 @@ def factor_wavefront_sweeps_jnp(op_row, op_lane, op_piv, op_dlane, op_dst,
         x = x.at[idx, lanes].set(jnp.where(valid, l, xp))
         return vals.at[rows].set(x), None
 
-    vals, _ = lax.scan(round_step, a_vals_ext, (op_row, op_lane, op_piv, op_dlane, op_dst))
+    with jax.named_scope("factor.rounds"):
+        vals, _ = lax.scan(round_step, a_vals_ext, (op_row, op_lane, op_piv, op_dlane, op_dst))
     return vals[:n]
 
 
@@ -97,7 +98,7 @@ def make_wavefront_factorizer(plan):
             jnp.asarray(vals, jnp.float32),
         )
 
-    return hoisted_jit(_raw)
+    return hoisted_jit(_raw, name="factorize")
 
 
 # --------------------------------------------------------------------------

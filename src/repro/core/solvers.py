@@ -359,7 +359,7 @@ def cg(matvec, b, precond=None, tol=1e-5, maxiter=500):
     M = precond or _identity
     b = jnp.asarray(b, jnp.float32)
     run = _cached_engine(matvec, M, ("cg", tol, maxiter), lambda: hoisted_jit(
-        functools.partial(_cg_core, matvec, M, tol=tol, maxiter=maxiter)))
+        functools.partial(_cg_core, matvec, M, tol=tol, maxiter=maxiter), name="cg"))
     x, it, rnorm, bnorm, hist, verdict = run(b)
     rel = float(rnorm) / max(float(bnorm), 1e-30)
     return SolveResult(np.asarray(x), int(it), rel, rel <= tol * 1.01,
@@ -420,7 +420,8 @@ def bicgstab(matvec, b, precond=None, tol=1e-5, maxiter=500):
     M = precond or _identity
     b = jnp.asarray(b, jnp.float32)
     run = _cached_engine(matvec, M, ("bicgstab", tol, maxiter), lambda: hoisted_jit(
-        functools.partial(_bicgstab_core, matvec, M, tol=tol, maxiter=maxiter)))
+        functools.partial(_bicgstab_core, matvec, M, tol=tol, maxiter=maxiter),
+        name="bicgstab"))
     x, it, rnorm, bnorm, hist, verdict = run(b)
     rel = float(rnorm) / max(float(bnorm), 1e-30)
     return SolveResult(np.asarray(x), int(it), rel, rel <= tol * 1.01,
@@ -449,6 +450,11 @@ def _gmres_core(matvec, M, b, m, tol, maxiter):
     contraction differently per fusion/batching context, so this is what
     makes a ``vmap``-batched lane produce exactly the bits of the same
     solve run alone — the batched-RHS bit-compat contract.
+
+    The phases carry ``jax.named_scope`` names (``gmres.spmv``,
+    ``gmres.precond``, ``gmres.orthogonalize``, ``gmres.qr``,
+    ``gmres.update``), which reach each compiled op's ``op_name`` and so a
+    profile's operations; names are metadata and change no bits.
     """
     n = b.shape[0]
     bnorm = bitnorm(b)
@@ -460,7 +466,10 @@ def _gmres_core(matvec, M, b, m, tol, maxiter):
 
         def arnoldi(carry, j):
             V, H = carry
-            w = matvec(M(V[j]))
+            with jax.named_scope("gmres.precond"):
+                z = M(V[j])
+            with jax.named_scope("gmres.spmv"):
+                w = matvec(z)
 
             # modified Gram-Schmidt
             def mgs(i, wh):
@@ -468,8 +477,9 @@ def _gmres_core(matvec, M, b, m, tol, maxiter):
                 hij = bitdot(V[i], w) * (i <= j)
                 return w - barred(hij * V[i]), h.at[i].set(hij)
 
-            w, h = jax.lax.fori_loop(0, m + 1, mgs, (w, jnp.zeros(m + 1, jnp.float32)))
-            hnext = bitnorm(w)
+            with jax.named_scope("gmres.orthogonalize"):
+                w, h = jax.lax.fori_loop(0, m + 1, mgs, (w, jnp.zeros(m + 1, jnp.float32)))
+                hnext = bitnorm(w)
             V = V.at[j + 1].set(w / jnp.maximum(hnext, 1e-30))
             H = H.at[:, j].set(h.at[j + 1].set(hnext))
             return (V, H), None
@@ -497,10 +507,11 @@ def _gmres_core(matvec, M, b, m, tol, maxiter):
             g = g.at[j + 1].set(-s * g[j]).at[j].set(c * g[j])
             return (cs.at[j].set(c), sn.at[j].set(s), g), (hcol[:m], jnp.abs(g[j + 1]))
 
-        (_cs, _sn, g), (r_cols, res_seq) = jax.lax.scan(
-            qr_col, (jnp.zeros(m, jnp.float32), jnp.zeros(m, jnp.float32), g0),
-            (H.T, jnp.arange(m)),
-        )
+        with jax.named_scope("gmres.qr"):
+            (_cs, _sn, g), (r_cols, res_seq) = jax.lax.scan(
+                qr_col, (jnp.zeros(m, jnp.float32), jnp.zeros(m, jnp.float32), g0),
+                (H.T, jnp.arange(m)),
+            )
         # useful steps: everything up to (and including) the first step that
         # cleared the tolerance; the masked tail contributes nothing below
         conv = res_seq <= tolb
@@ -516,16 +527,18 @@ def _gmres_core(matvec, M, b, m, tol, maxiter):
             den = jnp.where(kmask[j], R[j, j], 1.0)
             return y.at[j].set(num / den)
 
-        y = jax.lax.fori_loop(0, m, backsub, jnp.zeros(m, jnp.float32))
-
         # u = V[:m].T @ y as a fixed-order sequential combination (a matmul
         # reduces over m in a context-dependent order)
         def axpy(acc, vy):
             vj, yj = vy
             return acc + barred(yj * vj), None
 
-        u, _ = jax.lax.scan(axpy, jnp.zeros_like(r0), (V[:m], y))
-        return x0 + M(u), cnt
+        with jax.named_scope("gmres.update"):
+            y = jax.lax.fori_loop(0, m, backsub, jnp.zeros(m, jnp.float32))
+            u, _ = jax.lax.scan(axpy, jnp.zeros_like(r0), (V[:m], y))
+        with jax.named_scope("gmres.precond"):
+            du = M(u)
+        return x0 + du, cnt
 
     def outer_cond(carry):
         return carry[6] == VERDICT_RUNNING
@@ -534,7 +547,9 @@ def _gmres_core(matvec, M, b, m, tol, maxiter):
         x, r, it, res, hist, tot, verdict, stall = carry
         active = verdict == VERDICT_RUNNING  # freezes terminated vmap lanes
         x2, cnt = inner(x, r, res)
-        r2 = b - matvec(x2)
+        with jax.named_scope("gmres.spmv"):
+            ax2 = matvec(x2)
+        r2 = b - ax2
         rtrue = bitnorm(r2)
         # verdict/stall ride outside the iterate arithmetic: x2/r2/rtrue are
         # computed exactly as before, so classification changes no bits
@@ -561,7 +576,8 @@ def gmres_engine(matvec, M, restart, tol, maxiter):
     """The compiled single-RHS GMRES engine over ``matvec`` and the
     preconditioner ``M``, cached on ``matvec``."""
     return _cached_engine(matvec, M, ("gmres", restart, tol, maxiter), lambda: hoisted_jit(
-        functools.partial(_gmres_core, matvec, M, m=restart, tol=tol, maxiter=maxiter)))
+        functools.partial(_gmres_core, matvec, M, m=restart, tol=tol, maxiter=maxiter),
+        name="gmres"))
 
 
 def gmres(matvec, b, precond=None, restart=30, tol=1e-5, maxiter=20):
@@ -604,7 +620,8 @@ def gmres_batched(matvec, bs, precond=None, restart=30, tol=1e-5, maxiter=20) ->
     if tol_arr.ndim == 0:
         key = ("gmres_batched", restart, tol, maxiter)
         run = _cached_engine(matvec, M, key, lambda: hoisted_jit(jax.vmap(
-            functools.partial(_gmres_core, matvec, M, m=restart, tol=tol, maxiter=maxiter))))
+            functools.partial(_gmres_core, matvec, M, m=restart, tol=tol, maxiter=maxiter)),
+            name="gmres_batched"))
         x, rel, it, tot, hist, bnorm, verdict = run(bs)
         tols = np.full(bs.shape[0], float(tol), np.float32)
     else:
@@ -614,7 +631,8 @@ def gmres_batched(matvec, bs, precond=None, restart=30, tol=1e-5, maxiter=20) ->
                 f"matching the batch, got {tol_arr.shape}")
         key = ("gmres_batched_vtol", restart, maxiter)
         run = _cached_engine(matvec, M, key, lambda: hoisted_jit(jax.vmap(
-            lambda b, t: _gmres_core(matvec, M, b, m=restart, tol=t, maxiter=maxiter))))
+            lambda b, t: _gmres_core(matvec, M, b, m=restart, tol=t, maxiter=maxiter)),
+            name="gmres_batched"))
         x, rel, it, tot, hist, bnorm, verdict = run(bs, jnp.asarray(tol_arr))
         tols = tol_arr
     verdict = np.asarray(verdict)

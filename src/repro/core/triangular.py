@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .bitmath import exact_div, hoisted_jit, masked_lane_sum
 from .planner import (
     COL_SENTINEL,
@@ -143,6 +144,13 @@ def _slot_of_row(levels: np.ndarray, n: int) -> np.ndarray:
 
 
 def build_triangular_plan(pattern: ILUPattern, vals: np.ndarray) -> TriangularPlan:
+    """The wavefront schedules and level-major layout of both sweeps (the
+    ``ilu:plan.triangular`` span)."""
+    with obs.span("ilu:plan.triangular"):
+        return _build_triangular_plan(pattern, vals)
+
+
+def _build_triangular_plan(pattern: ILUPattern, vals: np.ndarray) -> TriangularPlan:
     n = pattern.n
     l_cols, l_vals, u_cols, u_vals, diag = _split_lu_ell(pattern, vals)
     # the shared vectorized Kahn scheduler (repro.core.planner) builds both
@@ -237,8 +245,8 @@ class PrecondApply:
                 d["u_diag"], d["u_rhs_idx"], d["out_perm"], b.astype(jnp.float32),
             )
 
-        self._apply = hoisted_jit(_raw)
-        self._batched = hoisted_jit(jax.vmap(_raw))
+        self._apply = hoisted_jit(_raw, name="precond_apply")
+        self._batched = hoisted_jit(jax.vmap(_raw), name="precond_apply_batched")
         self._aot = {}
 
     def __call__(self, b):
@@ -288,7 +296,9 @@ class PrecondApply:
 
 def wavefront_sweeps_jnp(l_cols, l_vals, l_rhs_idx, u_cols, u_vals, u_diag, u_rhs_idx, out_perm, b):
     """Fused L-then-U level-major wavefront sweep; every reduction goes
-    through ``masked_lane_sum``, the sequential substitution's lane order."""
+    through ``masked_lane_sum``, the sequential substitution's lane order.
+    The two level loops are named ``sweep.lower`` and ``sweep.upper``
+    (``jax.named_scope``: op metadata only)."""
     nl_lev, maxr_l, _ = l_cols.shape
     nu_lev, maxr_u, _ = u_cols.shape
     nl_slots = nl_lev * maxr_l
@@ -306,7 +316,8 @@ def wavefront_sweeps_jnp(l_cols, l_vals, l_rhs_idx, u_cols, u_vals, u_diag, u_rh
         return (x, start + maxr_l), None
 
     x_l = jnp.zeros(nl_slots + 1, jnp.float32)
-    (x_l, _), _ = jax.lax.scan(l_step, (x_l, 0), (l_cols, l_vals, l_rhs))
+    with jax.named_scope("sweep.lower"):
+        (x_l, _), _ = jax.lax.scan(l_step, (x_l, 0), (l_cols, l_vals, l_rhs))
 
     u_rhs = x_l[u_rhs_idx]  # (nu_lev, maxr_u) — y gathered from L slot space
 
@@ -319,7 +330,8 @@ def wavefront_sweeps_jnp(l_cols, l_vals, l_rhs_idx, u_cols, u_vals, u_diag, u_rh
         return (x, start + maxr_u), None
 
     x_u = jnp.zeros(nu_slots + 1, jnp.float32)
-    (x_u, _), _ = jax.lax.scan(u_step, (x_u, 0), (u_cols, u_vals, u_rhs, u_diag))
+    with jax.named_scope("sweep.upper"):
+        (x_u, _), _ = jax.lax.scan(u_step, (x_u, 0), (u_cols, u_vals, u_rhs, u_diag))
     return x_u[out_perm]
 
 

@@ -36,6 +36,7 @@ from typing import Callable, Dict, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.sparse import CSRMatrix
 
 from .admission import BREAKDOWN, QUEUE_FULL, UNKNOWN_MATRIX, AdmissionError
@@ -160,7 +161,8 @@ class PlanCache:
 
         if self.on_breakdown == "ignore":
             return engine.bind(a, vals_csr)
-        health = audit_values(pattern, vals_csr, self.pivot_tol)
+        with obs.span("ilu:push.audit"):
+            health = audit_values(pattern, vals_csr, self.pivot_tol)
         if health.ok:
             return engine.bind(a, vals_csr)
         if self.metrics is not None:

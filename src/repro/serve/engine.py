@@ -42,6 +42,7 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.core.sparse import CSRMatrix, ILUPattern
 
 #: serving defaults — one place, shared by engines / service / bench
@@ -73,7 +74,6 @@ class EngineBinding:
     version: int
     value_args: tuple
     vals_csr: np.ndarray
-    bound_seconds: float
     #: the CSRMatrix this binding's *matvec* values came from — the
     #: shift-retry path refactors `A + α·diag(‖row‖₁)` from it while the
     #: solve keeps targeting this exact A (Manteuffel: shift the
@@ -157,7 +157,7 @@ class ServeEngine:
             self._w_cols = jnp.asarray(plan0.w_cols)
             self._z_cols = jnp.asarray(plan0.z_cols)
 
-        self._jit = hoisted_jit(self._make_run())
+        self._jit = hoisted_jit(self._make_run(), name="serve_gmres")
         self._aot = {}
         self._versions = 0
 
@@ -202,32 +202,33 @@ class ServeEngine:
 
     # -- value binding ------------------------------------------------------
     def bind(self, a: CSRMatrix, vals_csr: np.ndarray) -> EngineBinding:
-        """Attach one value version: host-side scatter + device put, no
-        compilation (the inverse method runs the already-compiled value
-        sweep — same shapes, same executable)."""
+        """Attach one value version: host-side scatter (``ilu:push.rebind``)
+        + device put (``ilu:push.put``), no compilation (the inverse method
+        runs the already-compiled value sweep — same shapes, same
+        executable)."""
         import jax.numpy as jnp
 
-        t0 = time.perf_counter()
-        a_vals = np.zeros(self._a_ell_shape, np.float32)
-        a_vals[self._a_row_of, self._a_pos] = a.data
-        if self.precond_method == "sweep":
-            from repro.core.triangular import rebind_triangular_values
+        with obs.span("ilu:push.rebind"):
+            a_vals = np.zeros(self._a_ell_shape, np.float32)
+            a_vals[self._a_row_of, self._a_pos] = a.data
+            if self.precond_method == "sweep":
+                from repro.core.triangular import rebind_triangular_values
 
-            lv, uv, ud = rebind_triangular_values(self._tri_plan, self.pattern, vals_csr)
-            vargs = (jnp.asarray(a_vals), jnp.asarray(lv), jnp.asarray(uv), jnp.asarray(ud))
-        else:
-            from repro.core.inverse import build_inverse_plan, compute_inverse_values
+                p_vals = rebind_triangular_values(self._tri_plan, self.pattern, vals_csr)
+            else:
+                from repro.core.inverse import build_inverse_plan, compute_inverse_values
 
-            plan = build_inverse_plan(self.pattern, vals_csr, k=self.pattern.k)
-            w_vals, z_vals = compute_inverse_values(plan)
-            if w_vals.shape != self._w_cols.shape or z_vals.shape != self._z_cols.shape:
-                raise ValueError("ServeEngine.bind: inverse pattern changed shape — "
-                                 "values were bound against a different structure")
-            vargs = (jnp.asarray(a_vals), w_vals, z_vals)
+                plan = build_inverse_plan(self.pattern, vals_csr, k=self.pattern.k)
+                w_vals, z_vals = compute_inverse_values(plan)
+                if w_vals.shape != self._w_cols.shape or z_vals.shape != self._z_cols.shape:
+                    raise ValueError("ServeEngine.bind: inverse pattern changed shape — "
+                                     "values were bound against a different structure")
+                p_vals = (w_vals, z_vals)
+        with obs.span("ilu:push.put"):
+            vargs = (jnp.asarray(a_vals),) + tuple(jnp.asarray(v) for v in p_vals)
         self._versions += 1
         return EngineBinding(version=self._versions, value_args=vargs,
-                             vals_csr=np.asarray(vals_csr, np.float32),
-                             bound_seconds=time.perf_counter() - t0, a=a)
+                             vals_csr=np.asarray(vals_csr, np.float32), a=a)
 
     def bind_degraded(self, a: CSRMatrix, shift: float, factorize) -> Optional[EngineBinding]:
         """One rung of the serve-side shift ladder: factor
@@ -358,7 +359,6 @@ class ShardedServeEngine:
         from repro.core.api import ilu_sharded
         from repro.core.solvers import solve_sharded
 
-        t0 = time.perf_counter()
         fact = ilu_sharded(a, self.k, rule=self.rule, band_rows=self.band_rows,
                            mesh=self.mesh, precond_method=self.precond_method,
                            on_breakdown="ignore")
@@ -378,8 +378,7 @@ class ShardedServeEngine:
         self._versions += 1
         binding = EngineBinding(
             version=self._versions, value_args=(a, fact),
-            vals_csr=np.asarray(fact.values_csr(), np.float32),
-            bound_seconds=time.perf_counter() - t0, a=a)
+            vals_csr=np.asarray(fact.values_csr(), np.float32), a=a)
         return binding
 
     def bind_degraded(self, a: CSRMatrix, shift: float, factorize=None) -> Optional[EngineBinding]:
@@ -411,7 +410,7 @@ class ShardedServeEngine:
         return EngineBinding(
             version=self._versions, value_args=(a, fact),
             vals_csr=np.asarray(fact.values_csr(), np.float32),
-            bound_seconds=0.0, a=a, shift=float(shift))
+            a=a, shift=float(shift))
 
     def bucket_for(self, nb: int) -> int:
         from repro.core.solvers import bucket_batch

@@ -11,7 +11,8 @@ of ``BENCH_serve.json``. Three kinds of signals:
 * **Service counters** — queue depth (sampled per tick), coalesced-batch
   occupancy (real lanes / bucket lanes), cache hit/miss/evict/refactor
   counts, admission rejects by reason.
-* **XLA compile counter** — a process-global listener on jax's
+* **XLA compile counter** (``repro.obs``, re-exported here) — a
+  process-global listener on jax's
   ``/jax/core/compile/backend_compile_duration`` monitoring event. After
   warmup this number must go *flat*: any increment on the serving path
   means a request paid an XLA compile, which is exactly the failure mode
@@ -27,58 +28,13 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-# --------------------------------------------------------------------------
-# XLA compile counter
-# --------------------------------------------------------------------------
-_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_compile_lock = threading.Lock()
-_compile_count = 0
-_listener_installed = False
-
-
-def _on_event_duration(name: str, *args, **kw) -> None:
-    global _compile_count
-    if name == _COMPILE_EVENT:
-        with _compile_lock:
-            _compile_count += 1
-
-
-def install_compile_listener() -> None:
-    """Idempotently register the process-global backend-compile listener.
-
-    Must be installed before warmup for ``since_mark`` deltas to mean
-    anything; installing twice is a no-op (jax keeps listeners forever, so
-    a duplicate would double-count)."""
-    global _listener_installed
-    with _compile_lock:
-        if _listener_installed:
-            return
-        _listener_installed = True
-    import jax.monitoring
-
-    jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
-
-
-def compile_count() -> int:
-    """Total XLA backend compiles observed since the listener installed."""
-    with _compile_lock:
-        return _compile_count
-
-
-class CompileWatch:
-    """Snapshot-and-delta view of the process compile counter."""
-
-    def __init__(self):
-        install_compile_listener()
-        self._mark = compile_count()
-
-    def mark(self) -> int:
-        """Reset the baseline (call when warmup finishes); returns it."""
-        self._mark = compile_count()
-        return self._mark
-
-    def since_mark(self) -> int:
-        return compile_count() - self._mark
+# the compile counter lives with the program's other instrumentation;
+# re-exported here under the names the service has always used
+from repro.obs import (  # noqa: F401
+    CompileWatch,
+    compile_count,
+    install_compile_listener,
+)
 
 
 # --------------------------------------------------------------------------

@@ -224,8 +224,7 @@ class _StubEngine:
 
     def bind(self, a, vals_csr):
         self._v += 1
-        return types.SimpleNamespace(version=self._v, value_args=(), vals_csr=vals_csr,
-                                     bound_seconds=0.0)
+        return types.SimpleNamespace(version=self._v, value_args=(), vals_csr=vals_csr)
 
 
 def _cache(capacity=2):
