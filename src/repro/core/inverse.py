@@ -88,6 +88,16 @@ class InversePlan:
         return int((self.w_cols < self.n).sum() + (self.z_cols < self.n).sum())
 
 
+def _slot_of_row(levels: np.ndarray, n: int) -> np.ndarray:
+    """Map row id -> its slot index ``level * maxr + rank`` in the
+    level-major tables."""
+    slot = np.zeros(n, dtype=np.int64)
+    flat = levels.reshape(-1).astype(np.int64)
+    valid = flat < n
+    slot[flat[valid]] = np.nonzero(valid)[0]
+    return slot
+
+
 def _factor_tables(levels: np.ndarray, f_cols: np.ndarray, f_vals: np.ndarray,
                    inv_cols: np.ndarray, n: int):
     """Level-major tables for one factor's inverse value sweep (vectorized).
@@ -98,8 +108,6 @@ def _factor_tables(levels: np.ndarray, f_cols: np.ndarray, f_vals: np.ndarray,
     flat slot-major storage address, or to the trailing zero slot when the
     truncated pattern dropped (m, j) (the oracle's gathered 0.0).
     """
-    from .triangular import _slot_of_row
-
     nlev, maxr = levels.shape
     WI = inv_cols.shape[1]
     pad = levels >= n

@@ -9,9 +9,11 @@ The schedule is host-side planning (like Phase I) and is built **once** per
 factorization by :func:`build_triangular_plan` — fully vectorized NumPy, no
 per-row Python loops. Besides the row-major ELL factors it precomputes a
 *level-major* layout: rows are permuted so that each wavefront occupies one
-contiguous, padded slot. The device sweep then needs no row gathers and no
-scatters — per level it is one ``x[cols]`` gather, one masked lane-ordered
-reduction (:func:`repro.core.bitmath.masked_lane_sum`, bit-deterministic by
+contiguous, padded block, and runs of like-width levels share one padded
+shape (:class:`GroupedSweep`), so a wide level does not pad every other. The
+device sweep then needs no row gathers and no scatters — per level it is
+one ``x[cols]`` gather, one masked lane-ordered reduction
+(:func:`repro.core.bitmath.masked_lane_sum`, bit-deterministic by
 construction), and one ``dynamic_update_slice``. On the 16k-row Poisson
 benchmark this is ~4x faster per apply than the row-major scatter sweep.
 
@@ -46,18 +48,59 @@ from .sparse import ILUPattern
 
 
 @dataclasses.dataclass
+class GroupedSweep:
+    """One triangle's level-major sweep layout: contiguous runs of
+    like-width levels (:func:`level_groups`), each padded to its own widest
+    level (two rows at least) and its own longest row.
+
+    Group ``g`` holds ``(levels_g, rows_g, W_g)`` arrays, ``W_g`` possibly
+    0. The sweep vector is the concatenation of the group blocks plus one
+    scratch slot, ``n_slots``: row ``i`` of the group's level ``l`` lives at
+    slot ``offset_g + l * rows_g + i``. Every row keeps the lanes of the
+    sequential substitution in their order; only padding lanes past the
+    group's longest row are dropped, and those add +0.0 to an accumulator
+    that starts at +0.0, so the sums are bit for bit a wider layout's.
+    """
+
+    n_slots: int
+    cols: tuple  # per group (levels_g, rows_g, W_g) int32: dependency slots, pad -> n_slots
+    rhs_idx: tuple  # per group (levels_g, rows_g) int32: where each row's right-hand side is
+    val_pos: tuple  # per group (levels_g, rows_g, W_g) int32: CSR value positions, pad -> nnz
+    diag_pos: Optional[tuple] = None  # U: per group (levels_g, rows_g) int32, pad -> nnz + 1
+
+    @property
+    def lanes(self) -> int:
+        """(level, row, lane) cells one sweep gathers."""
+        return sum(c.size for c in self.cols)
+
+    def values(self, vals: np.ndarray) -> tuple:
+        """``(lane values, diagonals or None)`` of this layout for the
+        CSR-aligned factor values ``vals``: one host gather per group, the
+        padding reading 0.0 (lanes) and 1.0 (diagonals)."""
+        ext = np.concatenate([np.asarray(vals, np.float32), np.float32([0.0, 1.0])])
+        lanes = tuple(ext[p] for p in self.val_pos)
+        if self.diag_pos is None:
+            return lanes, None
+        return lanes, tuple(ext[p] for p in self.diag_pos)
+
+
+@dataclasses.dataclass
 class TriangularPlan:
-    """Padded wavefront schedule + ELL factors for L and U.
+    """Wavefront schedules + ELL factors for L and U, and the grouped
+    level-major layout the device sweep runs.
 
     Row-major fields (``l_cols`` … ``u_levels``) describe the classical
-    schedule; the ``*_lm`` fields are the level-major execution layout:
-    row ``l_levels[l, i]`` lives at slot ``l * maxr + i`` of the sweep
-    vector, column indices are pre-remapped into slot space (padding points
-    at the scratch slot ``n_slots``), and the right-hand side is fetched via
-    one precomputed gather.
+    schedule. ``lower`` / ``upper`` are the execution layout
+    (:class:`GroupedSweep`): each triangle's levels in contiguous groups of
+    like width, each group padded to itself rather than every level to the
+    widest level and the longest row. Column indices are pre-remapped into
+    slot space, the L right-hand side is one gather of ``b`` per group and
+    the U right-hand side one gather of the L sweep vector;
+    ``*_vals_lm`` / ``u_diag_lm`` hold the factor values in that layout.
     """
 
     n: int
+    nnz: int  # pattern entries the factor values are aligned with
     # unit-lower factor rows (strictly-below-diagonal entries)
     l_cols: np.ndarray  # (n, WL) int32, sentinel-padded
     l_vals: np.ndarray  # (n, WL) f32
@@ -68,32 +111,47 @@ class TriangularPlan:
     l_levels: np.ndarray  # (nl_levels, max_rows) int32, n-padded
     u_levels: np.ndarray  # (nu_levels, max_rows) int32, n-padded
 
-    # --- level-major execution layout (see class docstring) ---------------
-    nl_slots: int  # nl_levels * l_max_rows
-    nu_slots: int
-    l_cols_lm: np.ndarray  # (nl_levels, max_rows, WL) int32, slot-space, nl_slots-padded
-    l_vals_lm: np.ndarray  # (nl_levels, max_rows, WL) f32
-    l_rhs_idx: np.ndarray  # (nl_levels, max_rows) int32 into b_ext (padding -> n)
-    u_cols_lm: np.ndarray  # (nu_levels, max_rows, WU) int32, slot-space, nu_slots-padded
-    u_vals_lm: np.ndarray  # (nu_levels, max_rows, WU) f32
-    u_diag_lm: np.ndarray  # (nu_levels, max_rows) f32, 1-padded
-    u_rhs_idx: np.ndarray  # (nu_levels, max_rows) int32 into the L sweep vector
+    # --- grouped level-major execution layout (see class docstring) -------
+    lower: GroupedSweep  # rhs_idx into b_ext (padding -> n, the zero slot)
+    upper: GroupedSweep  # rhs_idx into the L sweep vector (padding -> its scratch slot)
     u_out_perm: np.ndarray  # (n,) int32: x[j] = x_u_sweep[u_out_perm[j]]
+    l_vals_lm: tuple  # per L group (levels_g, rows_g, W_g) f32
+    u_vals_lm: tuple  # per U group (levels_g, rows_g, W_g) f32
+    u_diag_lm: tuple  # per U group (levels_g, rows_g) f32, 1-padded
 
     @property
     def depth(self) -> int:
         return self.l_levels.shape[0] + self.u_levels.shape[0]
 
+    @property
+    def sweep_groups(self) -> tuple:
+        """Level groups of the (L, U) sweeps: one ``lax.scan`` each."""
+        return len(self.lower.cols), len(self.upper.cols)
+
+    @property
+    def lanes_per_apply(self) -> int:
+        """Lanes one apply gathers, padding included (L and U)."""
+        return self.lower.lanes + self.upper.lanes
+
+    @property
+    def factor_entries(self) -> int:
+        """Off-diagonal factor entries: the lanes an apply needs."""
+        return self.nnz - self.n
+
     def device_arrays(self) -> dict:
-        """The jnp arrays the fused wavefront sweep consumes, in call order."""
+        """The jnp arrays the fused wavefront sweep consumes, in call order
+        (a tuple of one array per group for each per-group table)."""
+        def dev(groups):
+            return tuple(jnp.asarray(g) for g in groups)
+
         return {
-            "l_cols": jnp.asarray(self.l_cols_lm),
-            "l_vals": jnp.asarray(self.l_vals_lm),
-            "l_rhs_idx": jnp.asarray(self.l_rhs_idx),
-            "u_cols": jnp.asarray(self.u_cols_lm),
-            "u_vals": jnp.asarray(self.u_vals_lm),
-            "u_diag": jnp.asarray(self.u_diag_lm),
-            "u_rhs_idx": jnp.asarray(self.u_rhs_idx),
+            "l_cols": dev(self.lower.cols),
+            "l_vals": dev(self.l_vals_lm),
+            "l_rhs_idx": dev(self.lower.rhs_idx),
+            "u_cols": dev(self.upper.cols),
+            "u_vals": dev(self.u_vals_lm),
+            "u_diag": dev(self.u_diag_lm),
+            "u_rhs_idx": dev(self.upper.rhs_idx),
             "out_perm": jnp.asarray(self.u_out_perm),
         }
 
@@ -124,28 +182,71 @@ def _split_lu_ell(pattern: ILUPattern, vals: np.ndarray):
     return l_cols, l_vals, u_cols, u_vals, diag
 
 
-def _level_major(levels: np.ndarray, cols: np.ndarray, vals: np.ndarray, n: int):
-    """Gather row-major ELL rows into the (nlev, maxr, W) level-major layout.
-    Padding rows get all-sentinel columns and zero values."""
-    pad = levels >= n
-    rows_c = np.minimum(levels, max(n - 1, 0))
-    c = np.where(pad[:, :, None], COL_SENTINEL, cols[rows_c]).astype(np.int32)
-    v = np.where(pad[:, :, None], 0.0, vals[rows_c]).astype(np.float32)
-    return c, v
+def level_groups(rows: np.ndarray) -> list:
+    """Cut a sweep's levels, given the rows of each, into contiguous runs
+    of like width: a run ends before the first level with more than twice,
+    or fewer than half, the rows of the run's widest level so far. Returns
+    ``[(lo, hi), ...]`` covering ``range(len(rows))`` in order."""
+    rows = [int(r) for r in rows]
+    spans, lo, top = [], 0, rows[0] if rows else 0
+    for i, r in enumerate(rows[1:], 1):
+        if r > 2 * top or 2 * r < top:
+            spans.append((lo, i))
+            lo, top = i, r
+        else:
+            top = max(top, r)
+    if rows:
+        spans.append((lo, len(rows)))
+    return spans
 
 
-def _slot_of_row(levels: np.ndarray, n: int) -> np.ndarray:
-    """Map row id -> its slot index ``level * maxr + rank`` in the sweep vector."""
-    slot = np.zeros(n, dtype=np.int64)
-    flat = levels.reshape(-1).astype(np.int64)
-    valid = flat < n
-    slot[flat[valid]] = np.nonzero(valid)[0]
-    return slot
+def _grouped_sweep(levels: np.ndarray, n: int, indices: np.ndarray, first: np.ndarray,
+                   length: np.ndarray, rhs_of: np.ndarray, diag_pos=None):
+    """The grouped layout of one triangle and the slot of each row.
+
+    ``levels`` is its wavefront table (``n``-padded, each level's rows
+    first); row ``r``'s lanes are the CSR entries ``first[r]`` …
+    ``first[r] + length[r] - 1`` (its strictly-lower or strictly-upper
+    part, in column order); ``rhs_of[r]`` is where its right-hand side is
+    gathered from, ``rhs_of[n]`` for a padding row; ``diag_pos[r]`` the CSR
+    position of its diagonal (U only)."""
+    nnz = indices.shape[0]
+    counts = np.count_nonzero(levels < n, axis=1)
+    # at least two rows a group: in a one-row block under ``vmap`` a lane's
+    # mask is one scalar for the whole batch, and XLA:CPU then contracts the
+    # masked product and the add into an FMA (the sums lose their bits)
+    levels = np.pad(levels.astype(np.int64), ((0, 0), (0, max(0, 2 - levels.shape[1]))),
+                    constant_values=n)
+    tabs = [levels[lo:hi, :max(int(counts[lo:hi].max()), 2)] for lo, hi in level_groups(counts)]
+    slot = np.zeros(n + 1, np.int64)
+    n_slots = 0
+    for tab in tabs:
+        valid = tab < n
+        slot[tab[valid]] = n_slots + np.flatnonzero(valid)
+        n_slots += tab.size
+    slot[n] = n_slots  # the scratch slot: reads +0.0, never written
+    col_slot = slot[np.append(indices, n)]  # CSR position -> slot of its column
+    first_x, length_x = np.append(first, 0), np.append(length, 0)  # pad row: no lanes
+    if diag_pos is not None:
+        diag_x = np.append(diag_pos, nnz + 1)
+    cols, rhs, pos, dpos = [], [], [], []
+    for tab in tabs:
+        lane = np.arange(int(length_x[tab].max(initial=0)))
+        p = np.where(lane < length_x[tab][..., None], first_x[tab][..., None] + lane, nnz)
+        pos.append(p.astype(np.int32))
+        cols.append(col_slot[p].astype(np.int32))
+        rhs.append(rhs_of[tab].astype(np.int32))
+        if diag_pos is not None:
+            dpos.append(diag_x[tab].astype(np.int32))
+    sweep = GroupedSweep(n_slots=n_slots, cols=tuple(cols), rhs_idx=tuple(rhs),
+                         val_pos=tuple(pos),
+                         diag_pos=None if diag_pos is None else tuple(dpos))
+    return sweep, slot[:n]
 
 
 def build_triangular_plan(pattern: ILUPattern, vals: np.ndarray) -> TriangularPlan:
-    """The wavefront schedules and level-major layout of both sweeps (the
-    ``ilu:plan.triangular`` span)."""
+    """The wavefront schedules and grouped level-major layout of both
+    sweeps (the ``ilu:plan.triangular`` span)."""
     with obs.span("ilu:plan.triangular"):
         return _build_triangular_plan(pattern, vals)
 
@@ -159,37 +260,24 @@ def _build_triangular_plan(pattern: ILUPattern, vals: np.ndarray) -> TriangularP
     # U solve runs bottom-up; dependencies are the above-diagonal columns
     u_levels = wavefront_schedule_ell(u_cols, n)
 
-    # --- level-major execution layout ------------------------------------
-    nl_slots = int(l_levels.size)
-    nu_slots = int(u_levels.size)
-    slot_l = _slot_of_row(l_levels, n)
-    slot_u = _slot_of_row(u_levels, n)
-
-    lc, lv = _level_major(l_levels, l_cols, l_vals, n)
-    # remap dependency columns (row ids) into L slot space; sentinel -> scratch
-    lc_m = np.where(
-        lc < COL_SENTINEL, slot_l[np.minimum(lc, max(n - 1, 0))], nl_slots
-    ).astype(np.int32)
-    l_rhs_idx = l_levels.astype(np.int32)  # padding slots already hold n (the zero slot)
-
-    uc, uv = _level_major(u_levels, u_cols, u_vals, n)
-    uc_m = np.where(
-        uc < COL_SENTINEL, slot_u[np.minimum(uc, max(n - 1, 0))], nu_slots
-    ).astype(np.int32)
-    pad_u = u_levels >= n
-    rows_u = np.minimum(u_levels, max(n - 1, 0))
-    u_diag_lm = np.where(pad_u, 1.0, diag[rows_u]).astype(np.float32)
+    # --- grouped level-major execution layout ------------------------------
+    row_start = pattern.indptr[:-1].astype(np.int64)
+    dptr = pattern.diag_ptr.astype(np.int64)
+    rowlen = np.diff(pattern.indptr).astype(np.int64)
+    lower, slot_l = _grouped_sweep(l_levels, n, pattern.indices, row_start, dptr,
+                                   np.arange(n + 1))  # padding -> n, b_ext's zero
     # the U right-hand side is the L sweep output, gathered from L slot space
-    u_rhs_idx = np.where(pad_u, nl_slots, slot_l[rows_u]).astype(np.int32)
-    u_out_perm = slot_u.astype(np.int32)
+    upper, slot_u = _grouped_sweep(u_levels, n, pattern.indices, row_start + dptr + 1,
+                                   rowlen - dptr - 1, np.append(slot_l, lower.n_slots),
+                                   diag_pos=row_start + dptr)
+    l_vals_lm, _ = lower.values(vals)
+    u_vals_lm, u_diag_lm = upper.values(vals)
 
     return TriangularPlan(
-        n=n, l_cols=l_cols, l_vals=l_vals, u_cols=u_cols, u_vals=u_vals,
+        n=n, nnz=pattern.nnz, l_cols=l_cols, l_vals=l_vals, u_cols=u_cols, u_vals=u_vals,
         diag=diag, l_levels=l_levels, u_levels=u_levels,
-        nl_slots=nl_slots, nu_slots=nu_slots,
-        l_cols_lm=lc_m, l_vals_lm=lv, l_rhs_idx=l_rhs_idx,
-        u_cols_lm=uc_m, u_vals_lm=uv, u_diag_lm=u_diag_lm,
-        u_rhs_idx=u_rhs_idx, u_out_perm=u_out_perm,
+        lower=lower, upper=upper, u_out_perm=slot_u.astype(np.int32),
+        l_vals_lm=l_vals_lm, u_vals_lm=u_vals_lm, u_diag_lm=u_diag_lm,
     )
 
 
@@ -199,25 +287,21 @@ def rebind_triangular_values(plan: TriangularPlan, pattern: ILUPattern, vals: np
 
     The wavefront schedule, the slot maps, and every column/index array are
     pure structure — only ``l_vals_lm`` / ``u_vals_lm`` / ``u_diag_lm``
-    depend on the numbers. This redoes just the value scatter (vectorized
-    NumPy, no scheduling, no compilation), so a serving cache can rebind a
-    background refactorization onto an already-compiled sweep whose value
-    operands ride as runtime arguments. Returns
-    ``(l_vals_lm, u_vals_lm, u_diag_lm)`` aligned with ``plan``.
+    depend on the numbers. This redoes just the value gathers through the
+    plan's precomputed CSR positions (NumPy, one gather per group, no
+    scheduling, no compilation), so a serving cache can rebind a background
+    refactorization onto an already-compiled sweep whose value operands
+    ride as runtime arguments. Returns ``(l_vals_lm, u_vals_lm,
+    u_diag_lm)`` aligned with ``plan``.
     """
-    n = plan.n
-    l_cols, l_vals, u_cols, u_vals, diag = _split_lu_ell(pattern, vals)
-    if l_cols.shape != plan.l_cols.shape or u_cols.shape != plan.u_cols.shape:
+    if pattern.n != plan.n or pattern.nnz != plan.nnz or np.shape(vals) != (plan.nnz,):
         raise ValueError(
             "rebind_triangular_values: pattern structure does not match the "
-            f"plan (L {l_cols.shape} vs {plan.l_cols.shape}, "
-            f"U {u_cols.shape} vs {plan.u_cols.shape})")
-    _, lv = _level_major(plan.l_levels, l_cols, l_vals, n)
-    _, uv = _level_major(plan.u_levels, u_cols, u_vals, n)
-    pad_u = plan.u_levels >= n
-    rows_u = np.minimum(plan.u_levels, max(n - 1, 0))
-    u_diag_lm = np.where(pad_u, 1.0, diag[rows_u]).astype(np.float32)
-    return lv, uv, u_diag_lm
+            f"plan (n {pattern.n} vs {plan.n}, nnz {pattern.nnz} vs {plan.nnz}, "
+            f"values {np.shape(vals)})")
+    l_vals_lm, _ = plan.lower.values(vals)
+    u_vals_lm, u_diag_lm = plan.upper.values(vals)
+    return l_vals_lm, u_vals_lm, u_diag_lm
 
 
 class PrecondApply:
@@ -295,60 +379,51 @@ class PrecondApply:
 
 
 def wavefront_sweeps_jnp(l_cols, l_vals, l_rhs_idx, u_cols, u_vals, u_diag, u_rhs_idx, out_perm, b):
-    """Fused L-then-U level-major wavefront sweep; every reduction goes
-    through ``masked_lane_sum``, the sequential substitution's lane order.
-    The two level loops are named ``sweep.lower`` and ``sweep.upper``
-    (``jax.named_scope``: op metadata only)."""
-    nl_lev, maxr_l, _ = l_cols.shape
-    nu_lev, maxr_u, _ = u_cols.shape
-    nl_slots = nl_lev * maxr_l
-    nu_slots = nu_lev * maxr_u
+    """Fused L-then-U grouped level-major wavefront sweep. Every argument
+    but ``out_perm`` and ``b`` is a tuple of one array per level group
+    (:class:`GroupedSweep`); each group is one ``lax.scan`` over its levels
+    (:func:`level_scan_jnp`), every reduction through ``masked_lane_sum``,
+    the sequential substitution's lane order. The group loops of the two
+    triangles run under the named scopes ``sweep.lower`` and
+    ``sweep.upper`` (``jax.named_scope``: op metadata only)."""
     b = b.astype(jnp.float32)
     b_ext = jnp.concatenate([b, jnp.zeros((1,), jnp.float32)])
-    l_rhs = b_ext[l_rhs_idx]  # (nl_lev, maxr_l)
-
-    def l_step(carry, inp):
-        x, start = carry
-        c, v, r = inp
-        gathered = x[c]  # padding -> scratch slot (0)
-        acc = masked_lane_sum(c, v, gathered, nl_slots)
-        x = jax.lax.dynamic_update_slice(x, r - acc, (start,))
-        return (x, start + maxr_l), None
-
-    x_l = jnp.zeros(nl_slots + 1, jnp.float32)
+    l_rhs = [b_ext[r] for r in l_rhs_idx]
     with jax.named_scope("sweep.lower"):
-        (x_l, _), _ = jax.lax.scan(l_step, (x_l, 0), (l_cols, l_vals, l_rhs))
-
-    u_rhs = x_l[u_rhs_idx]  # (nu_lev, maxr_u) — y gathered from L slot space
-
-    def u_step(carry, inp):
-        x, start = carry
-        c, v, r, d = inp
-        gathered = x[c]
-        acc = masked_lane_sum(c, v, gathered, nu_slots)
-        x = jax.lax.dynamic_update_slice(x, exact_div(r - acc, d), (start,))
-        return (x, start + maxr_u), None
-
-    x_u = jnp.zeros(nu_slots + 1, jnp.float32)
+        x_l = _grouped_sweep_jnp(l_cols, l_vals, l_rhs, None)
+    u_rhs = [x_l[r] for r in u_rhs_idx]  # y gathered from L slot space
     with jax.named_scope("sweep.upper"):
-        (x_u, _), _ = jax.lax.scan(u_step, (x_u, 0), (u_cols, u_vals, u_rhs, u_diag))
+        x_u = _grouped_sweep_jnp(u_cols, u_vals, u_rhs, u_diag)
     return x_u[out_perm]
 
 
-# --------------------------------------------------------------------------
-# band-partitioned triangular plan + sharded preconditioner apply
-# --------------------------------------------------------------------------
-def epoch_sweep_jnp(x, cols, vals, rhs, diag, start, limit):
-    """Device-local level-major scan over one collective epoch.
+def _grouped_sweep_jnp(cols, vals, rhs, diag):
+    """One triangle's groups in level order over one sweep vector (the
+    group blocks, then the scratch slot)."""
+    n_slots = sum(c.shape[0] * c.shape[1] for c in cols)
+    x = jnp.zeros(n_slots + 1, jnp.float32)
+    start = 0
+    for g, c in enumerate(cols):
+        x = level_scan_jnp(x, c, vals[g], rhs[g], None if diag is None else diag[g],
+                           start, n_slots)
+        start += c.shape[0] * c.shape[1]
+    return x
 
-    ``cols``/``vals``: (L_e, maxr, W) device-local dependency addresses +
+
+def level_scan_jnp(x, cols, vals, rhs, diag, start, limit):
+    """Level-major scan over consecutive levels of equal padded shape: one
+    level group of the single-device sweep, or one collective epoch of a
+    device's sharded sweep.
+
+    ``cols``/``vals``: (L_e, maxr, W) dependency addresses into ``x`` +
     values; ``rhs``: (L_e, maxr); ``diag``: (L_e, maxr) or None (L sweep —
-    unit diagonal); ``x``: the device-local sweep vector
-    ``[local | halo | scratch]``; ``start``: first write offset (= first
-    level × maxr); ``limit``: the scratch address (lanes at or past it are
-    padding and masked out of the reduction). All reductions go through
-    ``masked_lane_sum`` — the same lanes in the same order as the
-    single-device sweep, hence bitwise equal.
+    unit diagonal); ``x``: the sweep vector (device-local
+    ``[local | halo | scratch]`` when sharded); ``start``: first write
+    offset; level ``l`` writes its rows at ``start + l * maxr``; ``limit``:
+    the scratch address (lanes at or past it are padding and masked out of
+    the reduction). All reductions go through ``masked_lane_sum`` — the
+    same lanes in the same order as the sequential substitution, hence
+    bitwise equal.
     """
     maxr = cols.shape[1]
 
@@ -368,6 +443,9 @@ def epoch_sweep_jnp(x, cols, vals, rhs, diag, start, limit):
     return x
 
 
+# --------------------------------------------------------------------------
+# band-partitioned triangular plan + sharded preconditioner apply
+# --------------------------------------------------------------------------
 @dataclasses.dataclass
 class ShardedTriangularPlan:
     """Device-grouped level-major schedule over band-owned rows (DESIGN.md §5).
@@ -754,7 +832,7 @@ class ShardedTriangularEngine:
                 k = 0
                 for e in range(ls.n_epochs):
                     lo, hi = l_bounds[e], l_bounds[e + 1]
-                    x_l = epoch_sweep_jnp(x_l, lc[lo:hi], lv[lo:hi], l_r[lo:hi],
+                    x_l = level_scan_jnp(x_l, lc[lo:hi], lv[lo:hi], l_r[lo:hi],
                                       None, lo * maxr_l, ls.scratch)
                     if l_has[e] and D > 1:
                         allp = broadcast_payload(x_l[l_eg[k]], me)
@@ -766,7 +844,7 @@ class ShardedTriangularEngine:
                 k = 0
                 for e in range(us.n_epochs):
                     lo, hi = u_bounds[e], u_bounds[e + 1]
-                    x_u = epoch_sweep_jnp(x_u, uc[lo:hi], uv[lo:hi], u_r[lo:hi],
+                    x_u = level_scan_jnp(x_u, uc[lo:hi], uv[lo:hi], u_r[lo:hi],
                                       dg[lo:hi], lo * maxr_u, us.scratch)
                     if u_has[e] and D > 1:
                         allp = broadcast_payload(x_u[u_eg[k]], me)

@@ -206,6 +206,7 @@ class ServeEngine:
         + device put (``ilu:push.put``), no compilation (the inverse method
         runs the already-compiled value sweep — same shapes, same
         executable)."""
+        import jax
         import jax.numpy as jnp
 
         with obs.span("ilu:push.rebind"):
@@ -225,7 +226,7 @@ class ServeEngine:
                                      "values were bound against a different structure")
                 p_vals = (w_vals, z_vals)
         with obs.span("ilu:push.put"):
-            vargs = (jnp.asarray(a_vals),) + tuple(jnp.asarray(v) for v in p_vals)
+            vargs = jax.tree.map(jnp.asarray, (a_vals,) + tuple(p_vals))
         self._versions += 1
         return EngineBinding(version=self._versions, value_args=vargs,
                              vals_csr=np.asarray(vals_csr, np.float32), a=a)
@@ -301,8 +302,8 @@ class ServeEngine:
         for nb in buckets if buckets is not None else self.buckets:
             t0 = time.perf_counter()
             if nb not in self._aot:
-                vargs_sds = tuple(
-                    jax.ShapeDtypeStruct(v.shape, v.dtype) for v in binding.value_args)
+                vargs_sds = jax.tree.map(
+                    lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype), binding.value_args)
                 bs_sds = jax.ShapeDtypeStruct((nb, self.n), np.float32)
                 tol_sds = jax.ShapeDtypeStruct((nb,), np.float32)
                 self._aot[nb] = self._jit.lower(vargs_sds, bs_sds, tol_sds).compile()

@@ -221,8 +221,9 @@ def test_compiled_panel_update_matches_interpret(tpu):
 @pytest.mark.parametrize("seed,k", [(0, 1), (3, 2)])
 def test_wavefront_kernel_bit_identical_to_triangular_solver(seed, k):
     """The cached fused wavefront apply == the same sweep run eagerly on
-    the plan arrays, bit for bit: compiling it into an executable with its
-    plan arrays changes no rounding."""
+    the plan's grouped arrays (a tuple of one array per level group for each
+    table), bit for bit: compiling it into an executable with its plan
+    arrays changes no rounding."""
     from repro.core import matgen, numeric_ilu_ref, symbolic_ilu_k
     from repro.core.triangular import PrecondApply, wavefront_sweeps_jnp
 
@@ -232,6 +233,7 @@ def test_wavefront_kernel_bit_identical_to_triangular_solver(seed, k):
     b = np.random.default_rng(seed + 1).standard_normal(a.n).astype(np.float32)
     fused = PrecondApply(pat, vals)
     dev = fused.plan.device_arrays()
+    assert (len(dev["l_cols"]), len(dev["u_cols"])) == fused.plan.sweep_groups
     args = (dev["l_cols"], dev["l_vals"], dev["l_rhs_idx"], dev["u_cols"],
             dev["u_vals"], dev["u_diag"], dev["u_rhs_idx"], dev["out_perm"],
             jnp.asarray(b))
@@ -259,14 +261,15 @@ def _epoch_args(k=1, seed=5):
 
 @pytest.mark.parametrize("with_diag", [False, True])
 def test_epoch_sweep_kernel_bitwise(with_diag):
-    """The epoch-fused sweep == a sequential NumPy sweep of the same epoch,
-    bit for bit, for both the L (unit-diagonal) and U (divide) variants."""
-    from repro.core.triangular import epoch_sweep_jnp
+    """The level scan over one sharded epoch == a sequential NumPy sweep of
+    the same epoch, bit for bit, for both the L (unit-diagonal) and U
+    (divide) variants."""
+    from repro.core.triangular import level_scan_jnp
 
     x0, cols, vals, rhs, diag, scratch = _epoch_args()
     d = diag if with_diag else None
-    got = epoch_sweep_jnp(*(jnp.asarray(t) for t in (x0, cols, vals, rhs)),
-                          None if d is None else jnp.asarray(d), 0, scratch)
+    got = level_scan_jnp(*(jnp.asarray(t) for t in (x0, cols, vals, rhs)),
+                         None if d is None else jnp.asarray(d), 0, scratch)
     want = x0.copy()
     maxr = cols.shape[1]
     for lev in range(cols.shape[0]):

@@ -102,6 +102,32 @@ def test_gmres_engine_carries_its_scopes():
     assert any("/gmres.precond/sweep.upper/" in n for n in names)
 
 
+def test_every_sweep_group_loop_carries_its_scope():
+    """Each level group of the sweep is a loop of its own; every such loop
+    (and every loop inside the preconditioner) carries
+    ``gmres.precond/sweep.lower`` or ``sweep.upper`` in its ``op_name``, so
+    the metrics that read those scopes read the whole sweep. A group of one
+    level compiles to no loop; its ops carry the scope as well."""
+    from repro.core import matgen
+
+    a = matgen(120, density=0.06, seed=0)
+    fact = ilu(a, 1)
+    pre = fact.precond()
+    mv = make_ell_matvec(*csr_to_ell_arrays(a), a.n)
+    engine = gmres_engine(mv, pre, restart=5, tol=1e-5, maxiter=3)
+    text = engine.lower(jax.ShapeDtypeStruct((a.n,), jnp.float32)).compile().compiled.as_text()
+    loops = [n for line in text.splitlines() if " while(" in line
+             for n in re.findall(r'op_name="([^"]*)"', line)]
+    in_precond = [n for n in loops if "/gmres.precond/" in n]
+    assert all("/gmres.precond/sweep.lower/" in n or "/gmres.precond/sweep.upper/" in n
+               for n in in_precond)
+    for scope, sweep in (("sweep.lower", pre.plan.lower), ("sweep.upper", pre.plan.upper)):
+        multi = sum(c.shape[0] > 1 for c in sweep.cols)
+        assert multi > 1
+        # one group loop per multi-level group at each of the two M(...) calls
+        assert sum(n.endswith(f"/gmres.precond/{scope}/while") for n in in_precond) == 2 * multi
+
+
 def test_factor_engine_is_named_and_scoped():
     from repro.core.factor_plan import factor_plan_for
 

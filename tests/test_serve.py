@@ -332,6 +332,36 @@ def test_engine_rebind_is_bitwise_and_version_monotone():
             assert lanes[i].converged
 
 
+def test_engine_rebind_matches_a_fresh_plan_bitwise():
+    """Binding new values rebinds the grouped sweep's value arrays: they,
+    and the apply they give, equal a triangular plan built afresh on the
+    new values, bit for bit."""
+    import jax.numpy as jnp
+
+    from repro.core.triangular import PrecondApply, build_triangular_plan, wavefront_sweeps_jnp
+
+    a = matgen(200, 0.03, seed=1)  # a wide, dependency-free first L level
+    pattern = _symbolic(a, 1, "sum")
+    v1 = np.asarray(factor_plan_for(a, pattern).factorize(a))
+    eng = ServeEngine(a, pattern, v1, restart=8, buckets=(1,))
+    a2 = CSRMatrix(n=a.n, indptr=a.indptr, indices=a.indices,
+                   data=(a.data * 0.75).astype(np.float32))
+    v2 = np.asarray(factor_plan_for(a, pattern).factorize(a2))
+    bound = eng.bind(a2, v2).value_args[1:]
+    fresh = build_triangular_plan(pattern, v2)
+    assert fresh.sweep_groups[0] > 1
+    for got, want in zip(bound, (fresh.l_vals_lm, fresh.u_vals_lm, fresh.u_diag_lm)):
+        assert len(got) == len(want)
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g).view(np.int32), w.view(np.int32))
+    b = np.random.default_rng(3).standard_normal(a.n).astype(np.float32)
+    s = eng._p_static
+    x = wavefront_sweeps_jnp(s["l_cols"], bound[0], s["l_rhs_idx"], s["u_cols"], bound[1],
+                             bound[2], s["u_rhs_idx"], s["out_perm"], jnp.asarray(b))
+    want = PrecondApply(pattern, v2, plan=fresh)(jnp.asarray(b))
+    np.testing.assert_array_equal(np.asarray(x).view(np.int32), np.asarray(want).view(np.int32))
+
+
 # --------------------------------------------------------------------------
 # seeded coalescing-invariance check (the no-hypothesis fallback for the
 # property test in test_property.py — runs everywhere)

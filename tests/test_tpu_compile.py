@@ -113,7 +113,7 @@ def test_serve_bucket_program_compiles(system, one_chip):
     ``ServeEngine`` compiles it for a bound value version."""
     a, pattern, vals = system
     eng = ServeEngine(a, pattern, vals, restart=RESTART, maxiter=20, buckets=(8,))
-    vargs = tuple(_sds(v, one_chip) for v in eng.bind(a, vals).value_args)
+    vargs = jax.tree.map(lambda v: _sds(v, one_chip), eng.bind(a, vals).value_args)
     bs = jax.ShapeDtypeStruct((8, a.n), jnp.float32, sharding=one_chip)
     tols = jax.ShapeDtypeStruct((8,), jnp.float32, sharding=one_chip)
     _compile(eng._jit, vargs, bs, tols)
